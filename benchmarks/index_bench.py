@@ -686,6 +686,9 @@ def main(argv=None):
         os.environ["XLA_FLAGS"] = " ".join(
             [f"--xla_force_host_platform_device_count={args.mesh}"] + inherited
         )
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ns, ds, epss = tuple(args.n), tuple(args.d), tuple(args.eps)
     if args.grid:
         ns, ds, epss = (5000, 20000), (256, 768), (0.5, 0.55, 0.6)

@@ -1,9 +1,9 @@
 """Kernel microbenchmarks: ``name,us_per_call,derived`` CSV rows.
 
-CPU timings of the jnp oracles (the Pallas kernels execute via
-interpret=True here, which measures Python, not TPU — so the CSV times
-the *reference* computation and derives the kernel's TPU roofline bound
-from its analytic FLOPs/bytes instead)."""
+Times the jnp oracles on the default backend and derives each kernel's
+v5e roofline bound (``tpu_bound_us``) from its analytic FLOPs/bytes and
+the peaks table in :mod:`repro.launch.roofline`.  On a CPU the times
+measure XLA:CPU, not a TPU."""
 
 from __future__ import annotations
 
@@ -14,9 +14,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data.synthetic import sample_uniform_sphere
+from repro.launch.roofline import peaks
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+_V5E = peaks("TPU v5 lite")
+PEAK_FLOPS = _V5E["flops"]
+HBM_BW = _V5E["hbm_bw"]
 
 
 def _time(fn, *args, iters=5):

@@ -272,6 +272,9 @@ def main(argv=None):
         os.environ["XLA_FLAGS"] = " ".join(
             [f"--xla_force_host_platform_device_count={args.mesh}"] + inherited
         )
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     payload = run(args)
     if args.json:
         args.json.write_text(json.dumps(payload, indent=2, default=float))

@@ -22,7 +22,7 @@ entry points (the standard targets in :mod:`.targets`).
 * ``jaxpr-shardmap-replication`` (LAF104) — taint analysis of every
   ``shard_map`` eqn: an output whose value still depends on a mesh axis
   (sharded inputs, ``axis_index``) must declare that axis in its
-  ``out_names``.  The plane runs ``check_rep=False`` (the pallas calls
+  ``out_specs``.  The plane runs ``check_vma=False`` (the pallas calls
   defeat JAX's own rep checker), so this is the replication safety net:
   a dropped ``psum`` otherwise returns shard-local counts as if global.
 * ``jaxpr-packed-while-carry`` (LAF106) — no unsigned-dtype (packed
@@ -488,10 +488,10 @@ def _norm_axes(v) -> Taint:
     return _EMPTY
 
 
-def _names_axes(names) -> Taint:
-    """shard_map in_names/out_names entry ({dim: (axes...)}) -> axis set."""
+def _spec_axes(spec) -> Taint:
+    """shard_map in_specs/out_specs entry (a PartitionSpec) -> axis set."""
     out = set()
-    for axes in dict(names).values():
+    for axes in spec:
         out.update(_norm_axes(axes))
     return frozenset(out)
 
@@ -606,14 +606,12 @@ def _taint_cond(eqn, ins: List[Taint]) -> List[Taint]:
 
 def taint_shard_map_outputs(eqn) -> List[Tuple[Taint, Taint]]:
     """Per shard_map output: (residual_taint, declared_axes)."""
-    in_names = eqn.params["in_names"]
-    out_names = eqn.params["out_names"]
     body = _as_open(eqn.params["jaxpr"])
-    ins = [_names_axes(n) for n in in_names]
+    ins = [_spec_axes(s) for s in eqn.params["in_specs"]]
     outs = _taint_closed(body, ins)
     result = []
-    for t, names in zip(outs, out_names):
-        declared = _names_axes(names)
+    for t, spec in zip(outs, eqn.params["out_specs"]):
+        declared = _spec_axes(spec)
         result.append((t - declared, declared))
     return result
 
@@ -633,9 +631,9 @@ def check_jaxpr_shardmaps(jaxpr, label: str) -> List[Finding]:
                     Finding(
                         "jaxpr-shardmap-replication", label, 0,
                         f"shard_map output {k} still depends on mesh "
-                        f"axes {sorted(resid)} but out_names declares "
+                        f"axes {sorted(resid)} but out_specs declares "
                         f"only {sorted(declared) or 'replicated'} — "
-                        f"with check_rep=False each device returns its "
+                        f"with check_vma=False each device returns its "
                         f"shard-local value as if it were global",
                         hint="psum/all_gather over the residual axes "
                         "before returning, or declare the output "

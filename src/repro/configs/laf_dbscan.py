@@ -59,7 +59,7 @@ class LAFClusterConfig:
     # the tile shard-locally via the index plane
     # (repro.distributed.index_plane), with the packed signature table
     # co-sharded with the database rows; "auto" = the tile on every
-    # multi-device mesh and on accelerator-backed single devices (a
+    # multi-device mesh and on TPU-backed single devices (a
     # lone CPU device keeps the shardable jnp dataflow of the same
     # predicate).  index_axes names the mesh axes the db rows +
     # signature table are co-sharded over ("auto" = every mesh axis).

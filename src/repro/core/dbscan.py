@@ -113,7 +113,7 @@ def dbscan_parallel(
     (``"random_projection"`` or a fit instance) makes every range query
     cheaper at a bounded recall cost.  ``device`` picks the backend's
     evaluator (``True`` = fused Pallas tile, ``False`` = host numpy,
-    ``"auto"`` = tile iff a TPU/GPU is present); constructed backend
+    ``"auto"`` = tile iff a TPU is present); constructed backend
     instances keep their own setting.
     """
     from ..index import as_fitted

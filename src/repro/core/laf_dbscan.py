@@ -118,7 +118,7 @@ def laf_dbscan(
     backend="exact",
     device="auto",
     cluster_device="auto",
-    on_device_fault: str = "degrade",
+    on_device_fault: str = "raise",
 ) -> DBSCANResult:
     """Batch-parallel LAF-DBSCAN engine.
 
@@ -141,10 +141,10 @@ def laf_dbscan(
         ``True`` forces the device program even for host backends (the
         packed blocks are uploaded once — the exact-backend parity
         mode); ``False`` forces the host pass.
-      on_device_fault: ``"degrade"`` (default) falls back to the
-        bit-exact host unpack → union-find pass when the device cluster
-        launch fails (recording ``stream.degraded.cluster`` and an
-        ``slo.violation``); ``"raise"`` surfaces the failure.
+      on_device_fault: ``"raise"`` (default) surfaces a failure of
+        the device cluster launch; ``"degrade"`` (opt-in) falls back to
+        the bit-exact host unpack → union-find pass instead (recording
+        ``stream.degraded.cluster`` and an ``slo.violation``).
     """
     from ..index import as_fitted
 
@@ -248,7 +248,7 @@ def _cluster_pass_device(bk, eps, tau, exec_idx, n, native, block_size):
 def _laf_dbscan_body(
     data, eps, tau, alpha, predicted_counts, as_fitted,
     *, block_size, seed, backend, device, cluster_device="auto",
-    on_device_fault="degrade",
+    on_device_fault="raise",
 ):
     n = data.shape[0]
     with _span("laf.fit_index", backend=str(backend)):

@@ -36,7 +36,7 @@ class LAFPipeline:
     method (``repro.index``): ``"exact"`` (default), ``"random_projection"``,
     or a constructed ``RangeBackend`` instance; per-call ``backend=``
     kwargs override it.  ``device`` picks the backend evaluator (fused
-    Pallas tile vs host numpy; ``"auto"`` = tile iff TPU/GPU present)
+    Pallas tile vs host numpy; ``"auto"`` = tile iff a TPU is present)
     and is likewise overridable per call.  ``cluster_device`` routes
     cluster formation (``laf_dbscan``'s packed one-launch program vs
     the host union-find oracle; see ``LAFClusterConfig``).

@@ -74,21 +74,19 @@ def pack_bitmap(hits: np.ndarray) -> np.ndarray:
     Bit j of word w in row i is set iff hits[i, 32*w + j].
     """
     nq, nd = hits.shape
-    nw = _num_words(nd)
-    padded = np.zeros((nq, nw * 32), dtype=bool)
-    padded[:, :nd] = hits
-    bits = padded.reshape(nq, nw, 32).astype(np.uint32)
-    shifts = np.arange(32, dtype=np.uint32)
-    return (bits << shifts[None, None, :]).sum(axis=2, dtype=np.uint32)
+    # little-endian bytes of LSB-first bits, viewed 4 at a time, are the
+    # LSB-first uint32 words
+    packed = np.zeros((nq, _num_words(nd) * 4), dtype=np.uint8)
+    packed[:, : -(-nd // 8)] = np.packbits(hits, axis=1, bitorder="little")
+    return packed.view("<u4").astype(np.uint32, copy=False)
 
 
 def unpack_bitmap(bitmap: np.ndarray, nd: int) -> np.ndarray:
     """Inverse of :func:`pack_bitmap`."""
-    bitmap = np.asarray(bitmap)
-    nq, nw = bitmap.shape
-    shifts = np.arange(32, dtype=np.uint32)
-    bits = (bitmap[:, :, None] >> shifts[None, None, :]) & np.uint32(1)
-    return bits.reshape(nq, nw * 32)[:, :nd].astype(bool)
+    words = np.ascontiguousarray(bitmap, dtype="<u4")
+    return np.unpackbits(
+        words.view(np.uint8), axis=1, count=nd, bitorder="little"
+    ).view(bool)
 
 
 @functools.partial(jax.jit, static_argnames=("block_size",))

@@ -40,7 +40,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..index.signatures import shard_signatures, unpack_bits
@@ -54,7 +53,12 @@ from ..kernels.hamming_filter.ops import (
     hamming_filter_bitmap,
     hamming_filter_count,
 )
-from .sharding import axis_size, data_axes
+from ..kernels.label_prop.kernel import (
+    DEFAULT_ROW_TILE,
+    DEFAULT_WORD_TILE,
+    check_tiles as check_lp_tiles,
+)
+from .sharding import auto_axes, axis_size, data_axes
 
 __all__ = [
     "ShardPlan",
@@ -142,6 +146,7 @@ def shard_database(mesh: Mesh, data, sigs, axes=None, *, tile: int = 32):
     never move the table again.  ``tile`` aligns every shard to the
     kernel db tile (see :func:`shard_plan`).
     """
+    mesh = auto_axes(mesh)
     plan = shard_plan(mesh, data.shape[0], axes, tile=tile)
     spec = P(plan.axes, None)
     db = jax.device_put(
@@ -161,6 +166,7 @@ def _build_plane_fn(mesh: Mesh, axes, kind: str, q_tile: int, db_tile: int, inte
     """
     # body only runs on an lru_cache miss — i.e. a genuine plane rebuild
     _metrics.counter("plane.builds").inc()
+    mesh = auto_axes(mesh)
     rep = P(None, None)
     row_sharded = P(axes, None)
 
@@ -203,12 +209,12 @@ def _build_plane_fn(mesh: Mesh, axes, kind: str, q_tile: int, db_tile: int, inte
 
         out_specs = (P(), P(axes))
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(rep, row_sharded, rep, row_sharded, P(None), P(None)),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -372,6 +378,7 @@ def _build_sweep_plane_fn(
     same double-buffered slot as the count psum, so the pipeline
     overlap is unchanged)."""
     _metrics.counter("plane.builds").inc()
+    mesh = auto_axes(mesh)
     rep = P(None, None)
     row_sharded = P(axes, None)
     kw = dict(q_tile=q_tile, db_tile=db_tile, interpret=interpret)
@@ -454,12 +461,12 @@ def _build_sweep_plane_fn(
     # cached dispatch — eager shard_map re-traces per call, which would
     # cost more than the sweep itself
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(rep, rep, row_sharded, row_sharded, P(None), P(None)),
             out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -558,6 +565,7 @@ def _build_sweep_marginals_fn(
     mesh: Mesh, axes, q_tile: int, db_tile: int, interpret: bool, depth: int
 ):
     _metrics.counter("plane.builds").inc()
+    mesh = auto_axes(mesh)
     kw = dict(q_tile=q_tile, db_tile=db_tile, interpret=interpret)
 
     def body(qs, qss, db, dbs, eps, band):
@@ -603,7 +611,7 @@ def _build_sweep_marginals_fn(
         return counts, partial
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(
@@ -611,7 +619,7 @@ def _build_sweep_marginals_fn(
                 P(axes, None), P(axes, None), P(None), P(None),
             ),
             out_specs=(P(None, None), P(axes)),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -638,6 +646,7 @@ def _build_cluster_plane_fn(
     inside the round, so the outputs are replication-clean (LAF104).
     """
     _metrics.counter("plane.builds").inc()
+    mesh = auto_axes(mesh)
     from ..kernels.label_prop import packed_cluster_fixpoint
 
     ax = axes if isinstance(axes, tuple) else (axes,)
@@ -657,16 +666,16 @@ def _build_cluster_plane_fn(
             axes=ax, telemetry=telemetry,
         )
 
-    out_specs = (P(None), P(axes), P(axes), P(None), P(None))
+    out_specs = (P(None), P(axes), P(axes), P(None), P())
     if telemetry:
         out_specs = out_specs + ((P(None),) * 4,)
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(None, axes), P(None), P(None)),
             out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -680,8 +689,8 @@ def sharded_cluster_labels(
     axes,
     n: int,
     max_iters: int = 64,
-    row_tile: int = 256,
-    word_tile: int = 64,
+    row_tile: int = DEFAULT_ROW_TILE,
+    word_tile: int = DEFAULT_WORD_TILE,
     interpret=None,
     telemetry=None,
 ):
@@ -707,9 +716,12 @@ def sharded_cluster_labels(
     axes = tuple(axes) if isinstance(axes, (tuple, list)) else (axes,)
     w_loc = bitmap.shape[1] // axis_size(mesh, axes)
     # tiles must divide the shard-local slab exactly — padding local
-    # words would shift every later shard's global column indices
+    # words would shift every later shard's global column indices — and
+    # a compiled call must also meet the TPU block rule: raise, never
+    # fall back to a smaller illegal tile
     row_tile = math.gcd(bitmap.shape[0], row_tile)
     word_tile = math.gcd(w_loc, word_tile)
+    check_lp_tiles(bitmap.shape[0], w_loc, row_tile, word_tile, interpret)
     _metrics.counter("labelprop.launches").inc()
     f = _build_cluster_plane_fn(
         mesh, axes, n, max_iters, row_tile, word_tile, interpret,

@@ -18,9 +18,10 @@ from typing import Any, Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
+    "auto_axes",
     "named",
     "replicated",
     "param_sharding_rule",
@@ -37,6 +38,20 @@ def axis_size(mesh: Mesh, axes) -> int:
     if isinstance(axes, str):
         return int(mesh.shape[axes])
     return int(np.prod([mesh.shape[a] for a in axes]))
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """The same devices and axis names with every axis ``Auto``.
+
+    ``jax.make_mesh`` builds ``Explicit`` axes, under which an eager
+    slice of a sharded array and ``with_sharding_constraint`` refuse to
+    guess a layout.  The plane and the launch cells place and slice
+    their arrays themselves, so they run on the ``Auto`` form of
+    whatever mesh the caller built."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def named(mesh: Mesh, *spec) -> NamedSharding:

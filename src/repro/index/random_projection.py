@@ -27,9 +27,9 @@ Execution paths — **one contract, three evaluators**:
   implements the identical dual-threshold predicate per
   (q_tile × db_tile) tile: sure-accepts never touch the MXU and
   band-free tiles skip their verify matmul entirely.
-* ``device="auto"`` (default) — the kernel when a real accelerator
-  backs JAX, the host path otherwise, so CPU containers keep BLAS speed
-  while TPU/GPU sessions get the fused tile with zero configuration.
+* ``device="auto"`` (default) — the kernel when a TPU backs JAX, the
+  host path otherwise, so CPU containers keep BLAS speed while TPU
+  sessions get the fused tile with zero configuration.
 * ``mesh=`` — device evaluation additionally routes whole-database
   queries through the sharded index plane
   (``repro.distributed.index_plane``): ``fit`` co-shards the database
@@ -57,6 +57,7 @@ sets are identical (up to fp summation order on exact-boundary dots).
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Union
 
 import jax
@@ -71,6 +72,7 @@ from ..kernels.hamming_filter.ops import (
     hamming_filter_bitmap,
     hamming_filter_count,
 )
+from ..kernels.label_prop.kernel import WORD_TILE_ALIGN
 from ..obs import get_logger, metrics as _metrics, rate_limited_warn
 from ..testing import faults as _faults
 from ..train.fault_tolerance import GuardedStep
@@ -119,7 +121,7 @@ class RandomProjectionBackend(RangeBackend):
         chunks_per_launch: int = DEFAULT_CHUNKS_PER_LAUNCH,
         pipeline_depth: int = 2,
         donate="auto",
-        on_device_fault: str = "degrade",
+        on_device_fault: str = "raise",
         fault_retries: int = 2,
         fault_backoff_s: float = 0.02,
     ):
@@ -153,12 +155,13 @@ class RandomProjectionBackend(RangeBackend):
         self.chunks_per_launch = int(chunks_per_launch)
         self.pipeline_depth = int(pipeline_depth)
         self.donate = donate
-        # device-fault policy: "degrade" falls back to the bit-exact
-        # host oracle after ``fault_retries`` exponential-backoff
-        # retries; "raise" surfaces the failure to the caller.  Three
-        # consecutive degraded queries trip the sticky device-loss
-        # breaker (``_device_disabled``) — further queries go straight
-        # to host with no retry latency until the breaker is reset.
+        # device-fault policy: "raise" (default) surfaces a failure
+        # that survives ``fault_retries`` exponential-backoff retries;
+        # "degrade" (opt-in) falls back to the bit-exact host oracle
+        # instead.  Under "degrade", three consecutive degraded queries
+        # trip the sticky device-loss breaker (``_device_disabled``) —
+        # further queries go straight to host with no retry latency
+        # until the breaker is reset.
         self.on_device_fault = on_device_fault
         self.fault_retries = int(fault_retries)
         self.fault_backoff_s = float(fault_backoff_s)
@@ -196,6 +199,11 @@ class RandomProjectionBackend(RangeBackend):
         if self.device == "auto":
             return not default_interpret()
         return bool(self.device)
+
+    @property
+    def _interpret(self) -> bool:
+        """Whether the kernels run in the Pallas interpreter."""
+        return default_interpret() if self.interpret is None else bool(self.interpret)
 
     @property
     def _launch_site(self) -> str:
@@ -344,9 +352,14 @@ class RandomProjectionBackend(RangeBackend):
         from ..distributed.index_plane import shard_database
 
         # tile= aligns every shard to the kernel db tile so the sweep
-        # engine's scanned kernel calls never re-pad inside the loop
+        # engine's scanned kernel calls never re-pad inside the loop;
+        # compiled, each shard's slab words must also fill whole
+        # label-prop word tiles (the cluster pass cannot pad them)
+        tile = self.db_tile
+        if not self._interpret:
+            tile = math.lcm(tile, 32 * WORD_TILE_ALIGN)
         self._db_plane, self._sig_plane, self._plan = shard_database(
-            self.mesh, self._data, self._sigs, self.mesh_axes, tile=self.db_tile
+            self.mesh, self._data, self._sigs, self.mesh_axes, tile=tile
         )
 
     # -- durability --------------------------------------------------------
@@ -410,15 +423,11 @@ class RandomProjectionBackend(RangeBackend):
             and float(eps) not in self._occ_recorded
         ):
             # one sampled occupancy pass per (backend, eps) — feeds the
-            # index.band.* metrics the acceptance snapshot reports
+            # index.band.* metrics the acceptance snapshot reports.  On
+            # device it runs the stats build of the kernel, so a failure
+            # there is a device fault and propagates like one
             self._occ_recorded.add(float(eps))
-            try:
-                record_occupancy(self, eps)
-            except Exception as e:  # instrumentation must not break queries
-                rate_limited_warn(
-                    get_logger("index"), "occupancy", "occupancy_record_failed",
-                    error=type(e).__name__,
-                )
+            record_occupancy(self, eps)
         return t_lo, t_hi
 
     # -- host evaluation ---------------------------------------------------

@@ -20,11 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific pieces are unavailable in some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
-
 DEFAULT_BATCH_TILE = 8
 
 

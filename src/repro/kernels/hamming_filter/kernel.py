@@ -19,9 +19,20 @@ semantics (every candidate exact-checked).  Outputs match
 uint32 adjacency in the shared ``pack_bits`` bit order) so the two
 kernels are drop-in alternates for the engines.
 
-Tiling: q tile 128×d, db tile 256×d keeps q/db/score tiles plus the two
-signature tiles (128·w + 256·w uint32 words, w = n_bits/32 ≤ 32) well
-under VMEM; both matmul dims stay multiples of the 128-lane MXU tile.
+Layout.  Inside the kernel a tile is held **db-major**: db rows on the
+sublanes, query rows on the 128 lanes, so every per-query quantity is a
+lane-dense row vector.  Query signatures arrive word-major ``(w, nq)``,
+the counts leave as a ``(1, nq)`` row, and the packed adjacency leaves
+word-major ``(nd/32, nq)`` — a ``(db_tile/32, q_tile)`` = (8, 128) block
+per tile, bit ``b`` of word ``k`` being db row ``32k + b`` (LSB first,
+the ``pack_bits`` order).  :func:`hamming_filter_pallas` transposes the
+signatures in and the bitmap out, so callers keep the row-major
+``(nq, nd/32)`` contract.  The band scalars ride in SMEM.
+
+Compiled tiling: ``q_tile`` a multiple of 128 and ``db_tile`` a multiple
+of 256 (or the whole axis); q tile 128×d, db tile 256×d keeps the q/db
+tiles, the (256, 128) score tile and the signature tiles well under
+VMEM.  :func:`check_tiles` enforces this for compiled calls.
 """
 
 from __future__ import annotations
@@ -31,145 +42,133 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-# the shared traceable helpers work inside the kernel body too — one
-# definition of the popcount reduction and bit order across host/device
-from ...index.signatures import hamming_words as _tile_hamming
-from ...index.signatures import pack_bits as _pack_bits
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_Q_TILE = 128
 DEFAULT_DB_TILE = 256
+# compiled-kernel alignment: query rows ride the 128 lanes; db rows pack
+# 32 to a word and the word axis rides the 8 sublanes
+Q_TILE_ALIGN = 128
+DB_TILE_ALIGN = 256
+
+
+def check_tiles(nq: int, nd: int, q_tile: int, db_tile: int, interpret: bool) -> None:
+    """Raise unless the tiles divide the padded operands and, for a
+    compiled call, satisfy the TPU (8, 128) block rule."""
+    if nq % q_tile or nd % db_tile or db_tile % 32:
+        raise ValueError(
+            f"tiles (q_tile={q_tile}, db_tile={db_tile}) must divide the padded "
+            f"operands ({nq}, {nd}) and db_tile must be a multiple of 32"
+        )
+    if interpret:
+        return
+    if q_tile % Q_TILE_ALIGN and q_tile != nq:
+        raise ValueError(
+            f"compiled hamming_filter needs q_tile % {Q_TILE_ALIGN} == 0, got {q_tile}"
+        )
+    if db_tile % DB_TILE_ALIGN and db_tile != nd:
+        raise ValueError(
+            f"compiled hamming_filter needs db_tile % {DB_TILE_ALIGN} == 0, got {db_tile}"
+        )
 
 
 def _tile_masks(qs_ref, dbs_ref, band_ref):
-    """(accept, band) masks for one tile from its packed signatures;
-    band_ref holds [t_lo, t_hi]."""
-    ham = _tile_hamming(qs_ref[...], dbs_ref[...])
+    """(accept, band) masks, db-major (db_tile, q_tile), from the packed
+    signatures: ``dbs_ref`` (db_tile, w) row-major, ``qs_ref`` (w,
+    q_tile) word-major; band_ref (SMEM) holds [t_lo, t_hi]."""
+    qs = qs_ref[...]
+    dbs = dbs_ref[...]
+    ham = jnp.zeros((dbs.shape[0], qs.shape[1]), jnp.int32)
+    for k in range(qs.shape[0]):
+        x = dbs[:, k : k + 1] ^ qs[k : k + 1, :]
+        ham = ham + jax.lax.population_count(x).astype(jnp.int32)
     accept = ham <= band_ref[0]
-    band = (ham <= band_ref[1]) & ~accept
+    band = (ham <= band_ref[1]) & jnp.logical_not(accept)
     return accept, band
 
 
 def _verify_dots(q_ref, db_ref, thresh_ref):
-    q = q_ref[...].astype(jnp.float32)
     db = db_ref[...].astype(jnp.float32)
+    q = q_ref[...].astype(jnp.float32)
     dots = jax.lax.dot_general(
-        q, db, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        db, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
     return dots > thresh_ref[0]
 
 
-def _filter_count_kernel(q_ref, db_ref, qs_ref, dbs_ref, thresh_ref, band_ref, counts_ref):
-    """Grid (nq_tiles, nd_tiles); counts accumulate over the db axis."""
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
-
-    accept, band = _tile_masks(qs_ref, dbs_ref, band_ref)
-    # sure-accepts count without touching the MXU
-    counts_ref[...] += jnp.sum(accept, axis=1, dtype=jnp.int32)
-
-    @pl.when(jnp.any(band))
-    def _verify():
-        hit = band & _verify_dots(q_ref, db_ref, thresh_ref)
-        counts_ref[...] += jnp.sum(hit, axis=1, dtype=jnp.int32)
+def _any(mask):
+    return jnp.max(mask.astype(jnp.int32)) > 0
 
 
-def _filter_count_bitmap_kernel(
-    q_ref, db_ref, qs_ref, dbs_ref, thresh_ref, band_ref, counts_ref, bitmap_ref
-):
-    j = pl.program_id(1)
+def _col_count(hit):
+    """(db_tile, q_tile) bool -> (1, q_tile) int32 hits per query."""
+    return jnp.sum(hit.astype(jnp.int32), axis=0, keepdims=True)
 
-    @pl.when(j == 0)
-    def _init():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
 
-    accept, band = _tile_masks(qs_ref, dbs_ref, band_ref)
-    any_band = jnp.any(band)
-
-    @pl.when(any_band)
-    def _verify():
-        hit = accept | (band & _verify_dots(q_ref, db_ref, thresh_ref))
-        counts_ref[...] += jnp.sum(hit, axis=1, dtype=jnp.int32)
-        bitmap_ref[...] = _pack_bits(hit)
-
-    @pl.when(~any_band)
-    def _prune():
-        # band-free tile: sure-accepts (possibly none) are the whole
-        # answer — still no matmul
-        counts_ref[...] += jnp.sum(accept, axis=1, dtype=jnp.int32)
-        bitmap_ref[...] = _pack_bits(accept)
+def _pack_words(hit):
+    """(db_tile, q_tile) bool -> (db_tile/32, q_tile) uint32, bit b of
+    word k = db row 32k + b (LSB first; bits are disjoint, so the sum
+    is the OR)."""
+    td, tq = hit.shape
+    bits = hit.astype(jnp.int32).reshape(td // 32, 32, tq)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 32, 1), 1)
+    words = jnp.sum(bits << shifts, axis=1)
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)
 
 
 def _tile_stats(accept, band):
-    """[sure-accepts, band candidates, rejects] for one tile — the
-    occupancy triple the margin auto-tuner consumes (a tile's verify
+    """(1, 3) [sure-accepts, band candidates, rejects] for one tile —
+    the occupancy triple the margin auto-tuner consumes (a tile's verify
     matmul runs iff its band count is nonzero)."""
-    n_acc = jnp.sum(accept, dtype=jnp.int32)
-    n_band = jnp.sum(band, dtype=jnp.int32)
-    total = jnp.int32(accept.shape[0] * accept.shape[1])
-    return jnp.stack([n_acc, n_band, total - n_acc - n_band]).reshape(1, 3)
+    n_acc = jnp.sum(accept.astype(jnp.int32))
+    n_band = jnp.sum(band.astype(jnp.int32))
+    total = accept.shape[0] * accept.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 3), 1)
+    return jnp.where(
+        lane == 0, n_acc, jnp.where(lane == 1, n_band, total - n_acc - n_band)
+    )
 
 
-def _accumulate_stats(stats_ref, accept, band):
-    """Fold one tile's occupancy into the single whole-call (1, 3)
-    stats block (revisited on every grid step, counts-style): cheaper
-    than a per-tile output — one small accumulate per tile instead of
-    a (q_tiles, db_tiles, 3) slab write, which is what keeps the
-    telemetry build of the sweep near the plain build's cost."""
+def _filter_kernel(
+    q_ref, db_ref, qs_ref, dbs_ref, thresh_ref, band_ref, *out_refs,
+    with_bitmap: bool, with_stats: bool,
+):
+    """Grid (nq_tiles, nd_tiles); counts (and the stats block) accumulate
+    over the db axis, the bitmap is written once per tile."""
+    counts_ref = out_refs[0]
+    bitmap_ref = out_refs[1] if with_bitmap else None
+    stats_ref = out_refs[-1] if with_stats else None
     i, j = pl.program_id(0), pl.program_id(1)
 
-    @pl.when((i == 0) & (j == 0))
-    def _init():
-        stats_ref[...] = jnp.zeros_like(stats_ref)
-
-    stats_ref[...] += _tile_stats(accept, band)
-
-
-def _filter_count_stats_kernel(
-    q_ref, db_ref, qs_ref, dbs_ref, thresh_ref, band_ref, counts_ref, stats_ref
-):
-    j = pl.program_id(1)
-
     @pl.when(j == 0)
     def _init():
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
     accept, band = _tile_masks(qs_ref, dbs_ref, band_ref)
-    _accumulate_stats(stats_ref, accept, band)
-    counts_ref[...] += jnp.sum(accept, axis=1, dtype=jnp.int32)
+    if with_stats:
+        # one small accumulate per tile into the single whole-call block
+        # (revisited on every grid step) instead of a per-tile slab
+        @pl.when((i == 0) & (j == 0))
+        def _init_stats():
+            stats_ref[...] = jnp.zeros_like(stats_ref)
 
-    @pl.when(jnp.any(band))
-    def _verify():
-        hit = band & _verify_dots(q_ref, db_ref, thresh_ref)
-        counts_ref[...] += jnp.sum(hit, axis=1, dtype=jnp.int32)
-
-
-def _filter_count_bitmap_stats_kernel(
-    q_ref, db_ref, qs_ref, dbs_ref, thresh_ref, band_ref, counts_ref, bitmap_ref, stats_ref
-):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
-
-    accept, band = _tile_masks(qs_ref, dbs_ref, band_ref)
-    _accumulate_stats(stats_ref, accept, band)
-    any_band = jnp.any(band)
+        stats_ref[...] += _tile_stats(accept, band)
+    any_band = _any(band)
 
     @pl.when(any_band)
     def _verify():
         hit = accept | (band & _verify_dots(q_ref, db_ref, thresh_ref))
-        counts_ref[...] += jnp.sum(hit, axis=1, dtype=jnp.int32)
-        bitmap_ref[...] = _pack_bits(hit)
+        counts_ref[...] += _col_count(hit)
+        if with_bitmap:
+            bitmap_ref[...] = _pack_words(hit)
 
-    @pl.when(~any_band)
+    @pl.when(jnp.logical_not(any_band))
     def _prune():
-        counts_ref[...] += jnp.sum(accept, axis=1, dtype=jnp.int32)
-        bitmap_ref[...] = _pack_bits(accept)
+        # band-free tile: sure-accepts (possibly none) are the whole
+        # answer — no matmul
+        counts_ref[...] += _col_count(accept)
+        if with_bitmap:
+            bitmap_ref[...] = _pack_words(accept)
 
 
 @functools.partial(
@@ -197,6 +196,8 @@ def hamming_filter_pallas(
     order as ``repro.index.signatures``, one row per q/db row);
     ``(t_lo, t_hi)`` is the Hamming band (``t_lo = -1`` = full verify).
     Both thresholds are traced, so sweeping eps never recompiles.
+    Returns counts ``(nq,)`` int32, then with ``with_bitmap`` the packed
+    adjacency ``(nq, nd // 32)`` uint32 (row-major, LSB first).
 
     ``with_stats`` appends a (1, 3) int32 whole-call occupancy output:
     [sure-accepts, band candidates, rejects] summed over every tile's
@@ -210,61 +211,42 @@ def hamming_filter_pallas(
     nd = db.shape[0]
     w = q_sig.shape[1]
     assert db_sig.shape[1] == w
-    assert nq % q_tile == 0 and nd % db_tile == 0 and db_tile % 32 == 0
+    check_tiles(nq, nd, q_tile, db_tile, interpret)
     grid = (nq // q_tile, nd // db_tile)
     thresh = jnp.asarray([1.0 - eps], jnp.float32)
     band_t = jnp.stack(
         [jnp.asarray(t_lo, jnp.int32), jnp.asarray(t_hi, jnp.int32)]
     )
-
-    q_spec = pl.BlockSpec((q_tile, d), lambda i, j: (i, 0))
-    db_spec = pl.BlockSpec((db_tile, d), lambda i, j: (j, 0))
-    qs_spec = pl.BlockSpec((q_tile, w), lambda i, j: (i, 0))
-    dbs_spec = pl.BlockSpec((db_tile, w), lambda i, j: (j, 0))
-    scalar_spec = pl.BlockSpec(memory_space=pl.ANY)
-    counts_spec = pl.BlockSpec((q_tile,), lambda i, j: (i,))
-    stats_spec = pl.BlockSpec((1, 3), lambda i, j: (0, 0))
-    stats_shape = jax.ShapeDtypeStruct((1, 3), jnp.int32)
-    in_specs = [q_spec, db_spec, qs_spec, dbs_spec, scalar_spec, scalar_spec]
-    operands = (q, db, q_sig, db_sig, thresh, band_t)
-
-    if not with_bitmap:
-        if with_stats:
-            return pl.pallas_call(
-                _filter_count_stats_kernel,
-                grid=grid,
-                in_specs=in_specs,
-                out_specs=[counts_spec, stats_spec],
-                out_shape=[jax.ShapeDtypeStruct((nq,), jnp.int32), stats_shape],
-                interpret=interpret,
-            )(*operands)
-        return pl.pallas_call(
-            _filter_count_kernel,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=counts_spec,
-            out_shape=jax.ShapeDtypeStruct((nq,), jnp.int32),
-            interpret=interpret,
-        )(*operands)
-
-    bitmap_spec = pl.BlockSpec((q_tile, db_tile // 32), lambda i, j: (i, j))
-    bitmap_shape = jax.ShapeDtypeStruct((nq, nd // 32), jnp.uint32)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    in_specs = [
+        pl.BlockSpec((q_tile, d), lambda i, j: (i, 0)),
+        pl.BlockSpec((db_tile, d), lambda i, j: (j, 0)),
+        pl.BlockSpec((w, q_tile), lambda i, j: (0, i)),
+        pl.BlockSpec((db_tile, w), lambda i, j: (j, 0)),
+        smem,
+        smem,
+    ]
+    out_specs = [pl.BlockSpec((1, q_tile), lambda i, j: (0, i))]
+    out_shape = [jax.ShapeDtypeStruct((1, nq), jnp.int32)]
+    if with_bitmap:
+        out_specs.append(pl.BlockSpec((db_tile // 32, q_tile), lambda i, j: (j, i)))
+        out_shape.append(jax.ShapeDtypeStruct((nd // 32, nq), jnp.uint32))
     if with_stats:
-        return pl.pallas_call(
-            _filter_count_bitmap_stats_kernel,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=[counts_spec, bitmap_spec, stats_spec],
-            out_shape=[
-                jax.ShapeDtypeStruct((nq,), jnp.int32), bitmap_shape, stats_shape,
-            ],
-            interpret=interpret,
-        )(*operands)
-    return pl.pallas_call(
-        _filter_count_bitmap_kernel,
+        out_specs.append(pl.BlockSpec((1, 3), lambda i, j: (0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((1, 3), jnp.int32))
+    outs = pl.pallas_call(
+        functools.partial(
+            _filter_kernel, with_bitmap=with_bitmap, with_stats=with_stats
+        ),
         grid=grid,
         in_specs=in_specs,
-        out_specs=[counts_spec, bitmap_spec],
-        out_shape=[jax.ShapeDtypeStruct((nq,), jnp.int32), bitmap_shape],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )(*operands)
+    )(q, db, q_sig.T, db_sig, thresh, band_t)
+    res = [outs[0][0]]
+    if with_bitmap:
+        res.append(outs[1].T)
+    if with_stats:
+        res.append(outs[-1])
+    return res[0] if len(res) == 1 else tuple(res)

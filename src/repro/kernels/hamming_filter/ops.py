@@ -3,10 +3,11 @@ alignment, padded-row corrections, interpret switch — mirroring
 ``repro.kernels.range_count.ops``.
 
 ``interpret=None`` (the default) resolves per platform: the compiled
-kernel runs whenever a real accelerator backs the default JAX backend,
-and the Pallas interpreter is used otherwise (CPU containers, CI) — so
-callers get the fast path automatically without every call site having
-to remember the switch.  Tests pin ``interpret=True`` explicitly.
+kernel runs whenever a TPU backs the default JAX backend (the kernels
+are Mosaic TPU kernels), and the Pallas interpreter is used otherwise
+(CPU containers, CI) — so callers get the fast path automatically
+without every call site having to remember the switch.  Tests pin
+``interpret=True`` explicitly.
 """
 
 from __future__ import annotations
@@ -23,8 +24,8 @@ __all__ = ["hamming_filter_count", "hamming_filter_bitmap", "default_interpret"]
 
 
 def default_interpret() -> bool:
-    """True iff the compiled kernel cannot run here (no TPU/GPU)."""
-    return jax.default_backend() not in ("tpu", "gpu", "cuda", "rocm")
+    """True iff the compiled kernel cannot run here (no TPU)."""
+    return jax.default_backend() != "tpu"
 
 
 def _pad_rows(x: jax.Array, mult: int) -> jax.Array:

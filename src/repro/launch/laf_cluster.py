@@ -24,7 +24,7 @@ below ``t_lo``, exact-verify only the band).
 * ``"auto"`` (default) — the fused tile whenever it earns its keep: on
   any multi-device mesh (the sharded plane is the only evaluator that
   keeps range queries local to the data shard) and on single-device
-  meshes backed by a real accelerator; a single CPU device keeps the
+  meshes backed by a TPU; a single CPU device keeps the
   BLAS dataflow.  There is no single-device special case left in the
   routing — the plane degenerates to the plain wrapper on one device.
 
@@ -54,7 +54,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from ..configs.registry import ArchSpec, ShapeSpec
-from ..distributed.sharding import axis_size, named, replicated, tree_replicated
+from ..distributed.sharding import auto_axes, axis_size, named, replicated, tree_replicated
 from .cell import LoweredCell
 
 F32 = jnp.float32
@@ -67,6 +67,7 @@ def build_laf_cluster(arch: ArchSpec, shape: ShapeSpec, mesh: Mesh) -> LoweredCe
     from ..configs.laf_dbscan import LAFClusterConfig
     from ..core.cardinality.rmi import RMIConfig, init_rmi, rmi_predict_counts
 
+    mesh = auto_axes(mesh)
     base: LAFClusterConfig = arch.make_config()
     n, d = shape.meta["n_points"], shape.meta["dim"]
     # pad the database to a device multiple (zero rows never pass the
@@ -254,12 +255,17 @@ def build_one_launch_cluster(
     import math
 
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from ..configs.laf_dbscan import LAFClusterConfig
     from ..kernels.hamming_filter.ops import default_interpret
     from ..kernels.label_prop import packed_cluster_fixpoint
+    from ..kernels.label_prop.kernel import (
+        DEFAULT_ROW_TILE,
+        DEFAULT_WORD_TILE,
+        check_tiles,
+    )
 
+    mesh = auto_axes(mesh)
     base: LAFClusterConfig = arch.make_config()
     n = shape.meta["n_points"]
     frontier = base.frontier
@@ -270,10 +276,12 @@ def build_one_launch_cluster(
     cap = -(-n // (32 * n_shards)) * (32 * n_shards)
     w = cap // 32
     # tiles must divide the shard-local slab exactly (local padding
-    # would shift every later shard's global column indices)
-    row_tile = math.gcd(frontier, 256)
-    word_tile = math.gcd(w // n_shards, 64)
+    # would shift every later shard's global column indices); a
+    # compiled build raises on a tile the TPU block rule refuses
+    row_tile = math.gcd(frontier, DEFAULT_ROW_TILE)
+    word_tile = math.gcd(w // n_shards, DEFAULT_WORD_TILE)
     interpret = default_interpret()
+    check_tiles(frontier, w // n_shards, row_tile, word_tile, interpret)
     if base.telemetry == "auto":
         from ..obs import device_enabled
 
@@ -292,16 +300,16 @@ def build_one_launch_cluster(
             interpret=interpret, axes=axes, telemetry=tele_on,
         )
 
-    out_specs = (P(None), P(axes), P(axes), P(None), P(None))
+    out_specs = (P(None), P(axes), P(axes), P(None), P())
     if tele_on:
         # per-round telemetry vectors: psum'd in-loop, replicated out
         out_specs = out_specs + ((P(None),) * 4,)
-    step = shard_map(
+    step = jax.shard_map(
         cluster_one_launch,
         mesh=mesh,
         in_specs=(P(None, axes), P(None), P(None)),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
     args = (
         jax.ShapeDtypeStruct((frontier, w), jnp.uint32),

@@ -13,6 +13,7 @@ forces 512 host devices via XLA_FLAGS before any jax import).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_test_mesh"]
 
@@ -20,9 +21,11 @@ __all__ = ["make_production_mesh", "make_test_mesh"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(n_devices: int = 8):
     """Small mesh for CPU sharding tests (n must divide available devices)."""
-    return jax.make_mesh((n_devices // 4, 4), ("data", "model"))
+    return jax.make_mesh(
+        (n_devices // 4, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )

@@ -7,8 +7,9 @@ analysis:
     memory term     = HLO_bytes_per_device / HBM_bw
     collective term = collective_bytes_per_device / link_bw
 
-Hardware constants (TPU v5e per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.  The dominant term is the step-time lower bound; the
+Hardware constants come from :data:`PEAKS`, one entry per chip keyed by
+JAX's ``device_kind``; the dry-run cells target a v5e pod, so the table
+defaults to that kind.  The dominant term is the step-time lower bound; the
 roofline fraction = compute / max(terms) is the MFU-like score the perf
 loop drives up.  MODEL_FLOPS (6·N·D dense / 6·N_active·D MoE) over
 HLO_FLOPs exposes remat/redundancy waste.
@@ -29,13 +30,28 @@ from typing import Dict, List, Optional
 
 from ..obs import configure_logging, get_logger, log_event
 
-PEAK_FLOPS = 197e12      # bf16 / chip
-HBM_BW = 819e9           # bytes/s / chip
-LINK_BW = 50e9           # bytes/s / link
+# Published per-chip peaks keyed by ``jax.devices()[0].device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s of ICI over 4 links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+TARGET_DEVICE_KIND = "TPU v5 lite"  # the production mesh is a v5e pod
+
+
+def peaks(device_kind: str) -> dict:
+    """Peak FLOP/s and bytes/s of one chip of ``device_kind``; a kind
+    that is not in :data:`PEAKS` is an error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(
+            f"no published peaks for device_kind {device_kind!r}; "
+            f"known kinds: {sorted(PEAKS)}"
+        )
+    return PEAKS[device_kind]
 
 logger = get_logger("launch.roofline")
 
-__all__ = ["model_flops", "roofline_row", "build_table", "main"]
+__all__ = ["PEAKS", "peaks", "model_flops", "roofline_row", "build_table", "main"]
 
 
 def model_flops(meta: dict, kind: str, n_devices: int) -> Optional[float]:
@@ -75,7 +91,7 @@ class Row:
         return self.__dict__.copy()
 
 
-def roofline_row(rec: dict) -> Row:
+def roofline_row(rec: dict, device_kind: str = TARGET_DEVICE_KIND) -> Row:
     if rec.get("status") == "skip":
         return Row(rec["arch"], rec["shape"], rec["mesh"], "skip", note=rec.get("reason", ""))
     if rec.get("status") != "ok":
@@ -87,9 +103,10 @@ def roofline_row(rec: dict) -> Row:
     flops = h["flops"]
     mem_bytes = h["bytes_accessed"]
     coll = h["collectives"].get("total", {}).get("bytes", 0.0)
-    ct = flops / PEAK_FLOPS
-    mt = mem_bytes / HBM_BW
-    lt = coll / LINK_BW
+    pk = peaks(device_kind)
+    ct = flops / pk["flops"]
+    mt = mem_bytes / pk["hbm_bw"]
+    lt = coll / pk["link_bw"]
     terms = {"compute": ct, "memory": mt, "collective": lt}
     bound = max(terms, key=terms.get)
     mf = model_flops(rec.get("meta", {}), rec.get("meta", {}).get("kind", ""), rec["n_devices"])

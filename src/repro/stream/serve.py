@@ -17,7 +17,7 @@ sure are we?  ``ClusterIndex`` is an immutable snapshot built from a
    through the shared sweep engine (``repro.index.sweep``): a block of
    queries is verified against the union of its shortlisted clusters'
    members in **one** launch (``device="auto"`` routes through it
-   whenever a real accelerator backs JAX; the host numpy band loop is
+   whenever a TPU backs JAX; the host numpy band loop is
    retained as the oracle);
 3. **assignment** — the query joins the cluster holding the plurality
    of its eps-neighbors (DBSCAN's border rule, generalized to ties);

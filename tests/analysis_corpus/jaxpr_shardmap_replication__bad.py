@@ -7,7 +7,6 @@ Imported and executed by the corpus runner via build().
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -17,8 +16,8 @@ def build():
     def local_sum(x):
         return jnp.sum(x)  # no psum over "data"
 
-    f = shard_map(
+    f = jax.shard_map(
         local_sum, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return {"jaxpr": jax.make_jaxpr(f)(jnp.zeros((8,), jnp.float32))}

@@ -6,7 +6,6 @@ Imported and executed by the corpus runner via build().
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -16,8 +15,8 @@ def build():
     def global_sum(x):
         return jax.lax.psum(jnp.sum(x), "data")
 
-    f = shard_map(
+    f = jax.shard_map(
         global_sum, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return {"jaxpr": jax.make_jaxpr(f)(jnp.zeros((8,), jnp.float32))}

@@ -426,9 +426,11 @@ def test_fault_plan_grammar_and_determinism():
 # otherwise pre-warm the module-level sweep jit caches whose recompile
 # count test_sweep_recompiles_once_per_capacity_doubling asserts on.
 def _interp_backend(data=None):
+    # degrading to the host oracle is opt-in; these tests exercise it
     bk = RandomProjectionBackend(
         device=True, interpret=True, sweep=True,
         n_bits=128, margin=3.0, seed=3, chunk=64, q_tile=32, db_tile=64,
+        on_device_fault="degrade",
     )
     return bk if data is None else bk.fit(data)
 
@@ -485,6 +487,24 @@ def test_on_device_fault_raise_surfaces(small_angular):
             bk.query_counts(np.arange(16), 0.55)
 
 
+def test_device_fault_raises_by_default(small_angular, obs_sandbox):
+    """Degrading is opt-in: with no policy given, a device fault that
+    survives its retries reaches the caller and no degraded counter moves."""
+    bk = RandomProjectionBackend(
+        device=True, interpret=True, sweep=True, n_bits=128, margin=3.0,
+        seed=3, chunk=64, q_tile=32, db_tile=64, fault_retries=0,
+    ).fit(small_angular)
+    pc = np.full(len(small_angular), 10**9)
+    with faults.inject("seed=5,sweep.launch=1.0"):
+        with pytest.raises(faults.InjectedFault):
+            bk.query_counts(np.arange(16), 0.55)
+    with faults.inject("seed=3,cluster.launch=1.0"):
+        with pytest.raises(RuntimeError):
+            laf_dbscan(small_angular, 0.45, 4, 1.0, pc, backend="exact",
+                       cluster_device=True)
+    assert metrics.counter("stream.degraded.events").value == 0
+
+
 def test_cluster_launch_degrades_to_host_pass(small_angular, obs_sandbox):
     data = small_angular
     pc = np.full(len(data), 10**9)
@@ -492,7 +512,7 @@ def test_cluster_launch_degrades_to_host_pass(small_angular, obs_sandbox):
                      cluster_device=False)
     with faults.inject("seed=3,cluster.launch=1.0"):
         deg = laf_dbscan(data, 0.45, 4, 1.0, pc, backend="exact",
-                         cluster_device=True)
+                         cluster_device=True, on_device_fault="degrade")
     np.testing.assert_array_equal(ref.labels, deg.labels)
     assert metrics.counter("stream.degraded.cluster").value == 1
     assert metrics.counter("slo.violations").value >= 1

@@ -305,3 +305,20 @@ def test_suggest_margin_budget_monotone(plane_data):
     loose = suggest_margin(bk, EPS, max_band_frac=0.9)
     tight = suggest_margin(bk, EPS, max_band_frac=0.05)
     assert loose >= tight  # a bigger verify budget affords a wider band
+
+
+def test_sharded_cluster_tiles_raise_instead_of_shrinking():
+    """A shard-local slab whose words cannot fill a 128-word tile must
+    raise for a compiled launch, not fall back to a smaller gcd tile."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    from repro.distributed.index_plane import sharded_cluster_labels
+
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+    with pytest.raises(ValueError, match="word_tile"):
+        sharded_cluster_labels(
+            jnp.zeros((256, 136), jnp.uint32), jnp.arange(256, dtype=jnp.int32), 2,
+            mesh=mesh, axes=("data",), n=4000, interpret=False,
+        )

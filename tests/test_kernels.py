@@ -389,3 +389,59 @@ def test_hamming_filter_padded_row_sure_accept_correction():
         )
     )
     np.testing.assert_array_equal(got, np.full(nq, nd, np.int32))
+
+
+# ---------------------------------------------------------------------------
+# compiled-tile contract: a tile the TPU block rule refuses raises before
+# lowering (interpret mode accepts any tile that divides the operands)
+# ---------------------------------------------------------------------------
+
+
+def test_hamming_filter_compiled_tile_rule_raises():
+    from repro.kernels.hamming_filter.kernel import hamming_filter_pallas
+
+    q, db, q_sig, db_sig = _sig_case(64, 128, 32, 64, seed=29)
+    with pytest.raises(ValueError, match="q_tile"):
+        hamming_filter_pallas(
+            q, db, q_sig, db_sig, 0.5, -1, 64, q_tile=32, db_tile=64, interpret=False
+        )
+    with pytest.raises(ValueError, match="db_tile"):
+        hamming_filter_pallas(
+            q, db, q_sig, db_sig, 0.5, -1, 64, q_tile=64, db_tile=64, interpret=False
+        )
+
+
+@pytest.mark.parametrize("row_tile,word_tile,match", [(4, 2, "row_tile"), (8, 2, "word_tile")])
+def test_label_prop_compiled_tile_rule_raises(row_tile, word_tile, match):
+    from repro.kernels.label_prop.kernel import col_reduce_pallas, label_prop_rect_pallas
+
+    r, w = 16, 4
+    bitmap = jnp.zeros((r, w), jnp.uint32)
+    vec = jnp.zeros((r,), jnp.int32)
+    with pytest.raises(ValueError, match=match):
+        label_prop_rect_pallas(
+            vec, jnp.zeros((w * 32,), jnp.int32), bitmap,
+            row_tile=row_tile, word_tile=word_tile, interpret=False,
+        )
+    with pytest.raises(ValueError, match=match):
+        col_reduce_pallas(
+            bitmap, vec, vec, row_tile=row_tile, word_tile=word_tile, interpret=False
+        )
+
+
+@pytest.mark.parametrize("nd", [1, 31, 32, 33, 77, 100])
+def test_host_pack_bitmap_matches_pack_bits(nd):
+    """The host pack/unpack pair keeps the LSB-first word order of the
+    traceable ``pack_bits`` the kernels and the sweep use."""
+    from repro.core.range_query import pack_bitmap, unpack_bitmap
+    from repro.index.signatures import pack_bits
+
+    rng = np.random.default_rng(nd)
+    hits = rng.random((13, nd)) < 0.3
+    words = -(-nd // 32)
+    padded = np.zeros((13, words * 32), bool)
+    padded[:, :nd] = hits
+    packed = pack_bitmap(hits)
+    assert packed.dtype == np.uint32 and packed.shape == (13, words)
+    np.testing.assert_array_equal(packed, np.asarray(pack_bits(jnp.asarray(padded))))
+    np.testing.assert_array_equal(unpack_bitmap(packed, nd), hits)

@@ -24,7 +24,9 @@ SCRIPT = textwrap.dedent(
     import repro.launch.steps as S
     from repro.configs.registry import get_arch
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_test_mesh
+
+    mesh = make_test_mesh(8)
 
     # reduced configs so the 8-device compile stays fast; the production
     # builders are exercised unchanged (same sharding rules/step fns)
@@ -159,3 +161,58 @@ def test_roofline_row_classification():
     assert row.bound == "memory"          # 1e12/819e9 > 1e12/197e12, 1e9/50e9
     assert 0 < row.roofline_fraction < 1
     assert row.flops_ratio == pytest.approx(6 * 1e6 * 1024 / 256 / 1e12)
+
+
+def test_peaks_table_keyed_by_device_kind():
+    from repro.launch.roofline import PEAKS, peaks
+
+    assert peaks("TPU v5 lite") == PEAKS["TPU v5 lite"]
+    assert peaks("TPU v5 lite")["hbm_bw"] == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")
+
+
+def test_compile_cache_default_dir_is_fixed_in_checkout(monkeypatch):
+    import jax
+
+    from repro.launch import compile_cache
+
+    monkeypatch.delenv(compile_cache.CACHE_ENV, raising=False)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        got = compile_cache.enable_compile_cache()
+        assert got == str(compile_cache.DEFAULT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == got
+        assert (compile_cache.DEFAULT_CACHE_DIR.parent / "src" / "repro").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compile_cache_env_dir_receives_entries(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the helper sets nothing, and a
+    compile lands in that directory (fresh interpreter: JAX reads the
+    variable at import)."""
+    script = textwrap.dedent(
+        """
+        import sys
+        sys.path.insert(0, "src")
+        import jax, jax.numpy as jnp
+        from repro.launch.compile_cache import enable_compile_cache
+
+        before = jax.config.jax_compilation_cache_dir
+        got = enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == before == got, (before, got)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.jit(lambda x: jnp.sin(x) * 3)(jnp.ones(8)).block_until_ready()
+        print("RESULT:" + got)
+        """
+    )
+    import os
+
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"))
+    proc = subprocess.run(
+        [sys.executable, "-c", script], capture_output=True, text=True,
+        timeout=240, cwd=".", env=env,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert any((tmp_path / "cc").iterdir())
