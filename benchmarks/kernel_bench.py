@@ -14,9 +14,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data.synthetic import sample_uniform_sphere
-from repro.launch.roofline import peaks
+from repro.launch.roofline import TARGET_DEVICE_KIND, peaks
 
-_V5E = peaks("TPU v5 lite")
+_V5E = peaks(TARGET_DEVICE_KIND)
 PEAK_FLOPS = _V5E["flops"]
 HBM_BW = _V5E["hbm_bw"]
 

@@ -37,6 +37,7 @@ prints no result.  Everything runs in this one process.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import sys
@@ -74,20 +75,12 @@ class _Phases:
     def __init__(self):
         self.seconds = {}
 
+    @contextlib.contextmanager
     def __call__(self, name: str):
-        phases = self
-
-        class _Timer:
-            def __enter__(self):
-                self.t0 = time.perf_counter()
-
-            def __exit__(self, *exc):
-                dt = time.perf_counter() - self.t0
-                phases.seconds[name] = dt
-                if exc[0] is None:
-                    _log(f"phase {name}: {dt:.3f} s")
-
-        return _Timer()
+        t0 = time.perf_counter()
+        yield
+        self.seconds[name] = dt = time.perf_counter() - t0
+        _log(f"phase {name}: {dt:.3f} s")
 
 
 def _watch_pallas_builds(builds: list) -> None:

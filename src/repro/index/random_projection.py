@@ -29,7 +29,7 @@ Execution paths — **one contract, three evaluators**:
   band-free tiles skip their verify matmul entirely.
 * ``device="auto"`` (default) — the kernel when a TPU backs JAX, the
   host path otherwise, so CPU containers keep BLAS speed while TPU
-  sessions get the fused tile with zero configuration.
+  hosts get the fused tile with zero configuration.
 * ``mesh=`` — device evaluation additionally routes whole-database
   queries through the sharded index plane
   (``repro.distributed.index_plane``): ``fit`` co-shards the database

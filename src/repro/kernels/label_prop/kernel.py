@@ -19,8 +19,8 @@ Tiling: rows 256 × words 128 (=4096 columns) per grid step: the uint32
 tile is 128 KiB and the running-min tile the same — VMEM-resident with
 room for double buffering.  Compiled calls need ``row_tile % 8 == 0``
 and ``word_tile % 128 == 0`` (or the whole axis); :func:`check_tiles`
-raises otherwise.  The driver in ops.py iterates rounds with pointer
-jumping until fixpoint (O(log n) rounds for any topology).
+raises otherwise.  ``ops.py`` iterates rounds with pointer jumping
+until fixpoint (O(log n) rounds for any topology).
 
 Two grid orientations over the same packed words:
 

@@ -8,8 +8,8 @@ analysis:
     collective term = collective_bytes_per_device / link_bw
 
 Hardware constants come from :data:`PEAKS`, one entry per chip keyed by
-JAX's ``device_kind``; the dry-run cells target a v5e pod, so the table
-defaults to that kind.  The dominant term is the step-time lower bound; the
+JAX's ``device_kind``; the dry-run cells target a v5e pod, so the rows
+use that kind's peaks.  The dominant term is the step-time lower bound; the
 roofline fraction = compute / max(terms) is the MFU-like score the perf
 loop drives up.  MODEL_FLOPS (6·N·D dense / 6·N_active·D MoE) over
 HLO_FLOPs exposes remat/redundancy waste.
@@ -91,7 +91,7 @@ class Row:
         return self.__dict__.copy()
 
 
-def roofline_row(rec: dict, device_kind: str = TARGET_DEVICE_KIND) -> Row:
+def roofline_row(rec: dict) -> Row:
     if rec.get("status") == "skip":
         return Row(rec["arch"], rec["shape"], rec["mesh"], "skip", note=rec.get("reason", ""))
     if rec.get("status") != "ok":
@@ -103,7 +103,7 @@ def roofline_row(rec: dict, device_kind: str = TARGET_DEVICE_KIND) -> Row:
     flops = h["flops"]
     mem_bytes = h["bytes_accessed"]
     coll = h["collectives"].get("total", {}).get("bytes", 0.0)
-    pk = peaks(device_kind)
+    pk = peaks(TARGET_DEVICE_KIND)
     ct = flops / pk["flops"]
     mt = mem_bytes / pk["hbm_bw"]
     lt = coll / pk["link_bw"]
