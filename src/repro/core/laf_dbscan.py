@@ -226,22 +226,26 @@ def _cluster_pass_device(bk, eps, tau, exec_idx, n, native, block_size):
         per_round = _obs_device.harvest_cluster_telemetry(outs_h[5], rounds)
         _obs_device.emit_round_spans(getattr(lp_span, "_rec", None), per_round)
 
-    exact_counts = np.zeros(n, dtype=np.int64)
-    exact_counts[exec_idx] = np.asarray(counts[:n_exec], dtype=np.int64)
-    partial_counts = np.asarray(col_sum[:n], dtype=np.int64)
-    core = np.zeros(n, dtype=bool)
-    core[exec_idx] = exact_counts[exec_idx] >= tau
-    rep = np.asarray(rep[:n])
-    owner = np.asarray(owner[:n], dtype=np.int64)
-    labels = np.full(n, -1, dtype=np.int64)
-    ci = np.nonzero(core)[0]
-    if len(ci):
-        # rep = min core index per component == the union-find root the
-        # host pass produces (union_star merges by min root)
-        _, inv = np.unique(rep[ci], return_inverse=True)
-        labels[ci] = inv
-    borders = np.nonzero(~core & (owner < n))[0]
-    labels[borders] = labels[owner[borders]]
+    with _span("laf.assemble", n=n):
+        exact_counts = np.zeros(n, dtype=np.int64)
+        exact_counts[exec_idx] = np.asarray(counts[:n_exec], dtype=np.int64)
+        partial_counts = np.asarray(col_sum[:n], dtype=np.int64)
+        core = np.zeros(n, dtype=bool)
+        core[exec_idx] = exact_counts[exec_idx] >= tau
+        rep = np.asarray(rep[:n])
+        owner = np.asarray(owner[:n], dtype=np.int64)
+        labels = np.full(n, -1, dtype=np.int64)
+        ci = np.nonzero(core)[0]
+        if len(ci):
+            # rep = min core index per component == the union-find root the
+            # host pass produces (union_star merges by min root)
+            _, inv = np.unique(rep[ci], return_inverse=True)
+            labels[ci] = inv
+        borders = np.nonzero(~core & (owner < n))[0]
+        labels[borders] = labels[owner[borders]]
+        # the slab and the program's outputs are released here, inside
+        # the span, and not at the function's return
+        del slab, outs
     return labels, core, exact_counts, partial_counts
 
 
@@ -355,18 +359,31 @@ def _rescue_and_finish(
     # ---- post-processing: rescue false negatives (Algorithm 3) ---------
     rescue_idx = np.nonzero(~predicted_core & (partial_counts >= tau))[0]
     _metrics.counter("laf.rescued").inc(int(len(rescue_idx)))
+    pairs = visits = 0
     with _span("laf.postprocess", n_rescue=int(len(rescue_idx))):
         emap = PartialNeighborMap()
         if len(rescue_idx) > 0:
             for start in range(0, n_exec, block_size):
                 rows = exec_idx[start : start + block_size]
-                hit = bk.query_hits_subset(rows, rescue_idx, eps)  # (b, n_rescue)
-                for ri in np.nonzero(hit.any(axis=0))[0]:
-                    r = int(rescue_idx[ri])
-                    emap.register(r)
-                    emap[r].update(int(f) for f in rows[hit[:, ri]])
-        labels = post_processing(labels, emap, tau, rng=np.random.default_rng(seed))
-        labels = _compact(labels)
+                with _span("laf.rescue.sweep", rows=len(rows),
+                           cols=len(rescue_idx)) as sweep:
+                    hit = bk.query_hits_subset(rows, rescue_idx, eps)  # (b, n_rescue)
+                    sweep.sync_on(hit)
+                with _span("laf.rescue.emap", rows=len(rows)):
+                    hit_cols = np.nonzero(hit.any(axis=0))[0]
+                    for ri in hit_cols:
+                        r = int(rescue_idx[ri])
+                        emap.register(r)
+                        found = rows[hit[:, ri]]
+                        emap[r].update(int(f) for f in found)
+                        pairs += len(found)
+                visits += len(hit_cols)
+        with _span("laf.rescue.merge", entries=len(emap)):
+            labels = post_processing(labels, emap, tau, rng=np.random.default_rng(seed))
+            labels = _compact(labels)
+            del emap  # its sets of Python ints are freed here, inside the span
+    _metrics.counter("laf.rescue.pairs").inc(pairs)
+    _metrics.counter("laf.rescue.visits").inc(visits)
 
     extras = {
         "n_predicted_core": int(n_exec),

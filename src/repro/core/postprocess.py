@@ -21,6 +21,7 @@ from typing import Dict, List, Optional, Set
 
 import numpy as np
 
+from ..obs import metrics as _metrics
 from .union_find import UnionFind
 
 __all__ = ["PartialNeighborMap", "update_partial_neighbors", "post_processing"]
@@ -71,7 +72,8 @@ def post_processing(
     """Algorithm 3 with transitive merges via union-find.
 
     Returns updated labels (same id space; merged clusters collapse onto
-    the destination's representative id).
+    the destination's representative id).  The points it assigns are
+    counted into ``laf.rescue.merged``.
     """
     rng = rng or np.random.default_rng(0)
     labels = labels.copy()
@@ -96,6 +98,7 @@ def post_processing(
             uf.union(dest, int(c))
         rescued.append((int(p), dest))
 
+    _metrics.counter("laf.rescue.merged").inc(len(rescued))
     remap = np.array([uf.find(c) for c in range(n_clusters)], dtype=np.int64)
     mask = labels >= 0
     labels[mask] = remap[labels[mask]]

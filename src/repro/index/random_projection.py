@@ -83,6 +83,7 @@ from .signatures import (
     hamming_words,
     make_projection,
     sign_signatures,
+    upload,
 )
 from .sweep import (
     DEFAULT_CHUNKS_PER_LAUNCH,
@@ -494,12 +495,12 @@ class RandomProjectionBackend(RangeBackend):
 
     def _device_data(self):
         if self._data_dev is None:
-            self._data_dev = jnp.asarray(self._data_buf)
+            self._data_dev = upload(self._data_buf, "data")
         return self._data_dev
 
     def _device_sigs(self):
         if self._sigs_dev is None:
-            self._sigs_dev = jnp.asarray(self._sigs_buf)
+            self._sigs_dev = upload(self._sigs_buf, "sigs")
         return self._sigs_dev
 
     def _host_sigs(self):
@@ -531,17 +532,10 @@ class RandomProjectionBackend(RangeBackend):
             if pad == 0:
                 self._sweep_dev = (self._device_data(), self._device_sigs())
             else:
-                db = np.zeros(
-                    (self._data_buf.shape[0] + pad, self._data_buf.shape[1]),
-                    dtype=np.float32,
+                self._sweep_dev = (
+                    upload(self._data_buf, "sweep_data", pad_rows=pad),
+                    upload(self._sigs_buf, "sweep_sigs", pad_rows=pad),
                 )
-                db[: self._data_buf.shape[0]] = self._data_buf
-                dbs = np.zeros(
-                    (self._sigs_buf.shape[0] + pad, self._sigs_buf.shape[1]),
-                    dtype=np.uint32,
-                )
-                dbs[: self._sigs_buf.shape[0]] = self._sigs_buf
-                self._sweep_dev = (jnp.asarray(db), jnp.asarray(dbs))
         return self._sweep_dev
 
     def _sweep_q(self, rows: np.ndarray):

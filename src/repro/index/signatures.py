@@ -23,8 +23,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import metrics as _metrics, span as _span
+
 __all__ = [
     "make_projection",
+    "upload",
     "pack_bits",
     "unpack_bits",
     "sign_signatures",
@@ -87,6 +90,24 @@ def hamming_words(a: jax.Array, b: jax.Array) -> jax.Array:
     return ham
 
 
+def upload(host: np.ndarray, what: str, *, pad_rows: int = 0) -> jax.Array:
+    """Copy one database-sized operand to the device, zero-padded by
+    ``pad_rows`` rows first (the padded host copy is built inside).
+
+    Each copy is one ``laf.upload`` span, synced on the device array so
+    that with tracing on it times the transfer, not the enqueue, and
+    adds its ``nbytes`` to the ``index.upload.bytes`` counter."""
+    with _span("laf.upload", what=what) as sp:
+        if pad_rows:
+            buf = np.zeros((host.shape[0] + pad_rows,) + host.shape[1:], host.dtype)
+            buf[: host.shape[0]] = host
+            host = buf
+        dev = jnp.asarray(host)
+        sp.set(rows=int(host.shape[0]), bytes=int(host.nbytes)).sync_on(dev)
+    _metrics.counter("index.upload.bytes").inc(int(host.nbytes))
+    return dev
+
+
 @jax.jit
 def _sign_pack(data: jax.Array, proj: jax.Array) -> jax.Array:
     return pack_bits((data @ proj) >= 0.0)
@@ -94,7 +115,8 @@ def _sign_pack(data: jax.Array, proj: jax.Array) -> jax.Array:
 
 def sign_signatures(data: np.ndarray, proj: np.ndarray) -> np.ndarray:
     """Packed (n, n_bits // 32) uint32 sign signatures of ``data @ proj``."""
-    return np.asarray(_sign_pack(jnp.asarray(data, jnp.float32), jnp.asarray(proj)))
+    corpus = upload(np.asarray(data, np.float32), "corpus")
+    return np.asarray(_sign_pack(corpus, jnp.asarray(proj)))
 
 
 def shard_signatures(mesh, sigs, spec=None, *, n_padded: int | None = None):

@@ -89,7 +89,7 @@ class SLOResult:
 
 
 # default rule sets — loose sanity floors, replaced per deployment via
-# set_slos(); thresholds mirror the bench-trajectory gate's quantities
+# set_slos()
 SERVE_SLOS: List[SLO] = [
     SLO(
         "serve-assign-p99", "serve.assign.latency_s:p99", "<=", 0.5,

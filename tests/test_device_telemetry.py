@@ -2,8 +2,7 @@
 counters vs a bit-exact numpy oracle (single device and 4-forced-device
 mesh, ragged n), sweep occupancy slab parity vs the per-chunk kernel
 stats, synthetic per-round span round-trip through the Chrome trace,
-the histogram zero-clamp, the SLO plane, and the bench-trajectory
-drift gate."""
+the histogram zero-clamp, and the SLO plane."""
 
 import json
 
@@ -378,66 +377,3 @@ def test_default_slo_sets_cover_the_stack():
     ):
         assert rules, kind
         assert all(isinstance(r, slo.SLO) for r in rules)
-
-
-# ---------------------------------------------------------------------------
-# bench-trajectory drift gate
-# ---------------------------------------------------------------------------
-
-
-def _trajectory():
-    import importlib.util
-    from pathlib import Path
-
-    path = (
-        Path(__file__).resolve().parents[1] / "benchmarks" / "trajectory.py"
-    )
-    spec = importlib.util.spec_from_file_location("bench_trajectory", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_trajectory_update_then_gate_roundtrip(tmp_path):
-    tj = _trajectory()
-    hist = {"metrics": {}}
-    payload = {"rows": [{"cluster_speedup": 2.0, "ari_one_launch_vs_host": 1.0}],
-               "worst_ari": 0.999}
-    improved = tj.update(payload, "lineage", hist, source="a.json")
-    assert set(improved) == {
-        "lineage:cluster_speedup", "lineage:ari_one_launch_vs_host",
-        "lineage:worst_ari",
-    }
-    # same payload gates clean against its own history
-    assert tj.gate(payload, "lineage", hist) == []
-    # tight metric: a 30% ARI drop fails at the 20% tolerance
-    bad = {"worst_ari": 0.69, "rows": []}
-    fails = tj.gate(bad, "lineage", hist)
-    assert len(fails) == 1 and "worst_ari" in fails[0]
-    # noisy metric: a 50% wall-clock regression passes the 60% band,
-    # an 80% one does not
-    hist2 = {"metrics": {}}
-    tj.update({"best_cluster_speedup": 10.0}, "l", hist2)
-    assert tj.gate({"best_cluster_speedup": 5.0}, "l", hist2) == []
-    assert tj.gate({"best_cluster_speedup": 2.0}, "l", hist2)
-    # an unknown lineage never fails (first observation seeds it)
-    assert tj.gate(payload, "other-lineage", hist) == []
-    # round-trip through disk
-    p = tmp_path / "hist.json"
-    tj.save_history(hist, p)
-    assert tj.load_history(p) == hist
-
-
-def test_trajectory_checked_in_history_self_consistent():
-    tj = _trajectory()
-    hist = tj.load_history()
-    assert hist["metrics"], "benchmarks/history/trajectory.json is empty"
-    for key, ent in hist["metrics"].items():
-        name = key.split(":", 1)[1]
-        assert name in tj.METRICS, key
-        direction, noisy = tj.METRICS[name]
-        assert ent["direction"] == direction and ent["noisy"] == noisy
-        assert ent["best"] is not None and ent["history"]
-        best = ent["best"]
-        vals = [h["value"] for h in ent["history"]]
-        assert best == (max(vals) if direction == "higher" else min(vals))

@@ -12,6 +12,7 @@ import pytest
 
 from repro import obs
 from repro.data.synthetic import make_angular_clusters
+from repro.core.laf_dbscan import laf_dbscan
 from repro.index import RandomProjectionBackend
 from repro.index.random_projection import record_occupancy
 from repro.obs import metrics
@@ -273,3 +274,142 @@ def test_band_lazily_records_occupancy_once_per_eps(obs_data):
         )
         > total
     )
+
+
+# ---------------------------------------------------------------------------
+# the rescue's three parts, the database uploads and the host assembly
+# ---------------------------------------------------------------------------
+
+RESCUE_TAU = 5
+
+
+def _run_rescue_case(data, trace: bool) -> dict:
+    """One device-path LAF run (interpreted kernels) whose rescue is not
+    empty: 40% of the points are predicted to stop whatever their count.
+    Records the spans, the counters, the map 𝓔 that Algorithm 3 received
+    and every ``jax.block_until_ready`` made from inside the rescue."""
+    import importlib
+    import sys
+
+    import jax
+
+    from repro.core.range_query import range_counts
+
+    laf_mod = importlib.import_module("repro.core.laf_dbscan")
+
+    counts = np.asarray(range_counts(data, data, EPS)).astype(np.float64)
+    rng = np.random.default_rng(0)
+    pred = np.where(rng.random(len(data)) < 0.4, 0.0, counts)
+    seen, blocks = {}, []
+    real_pp, real_block = laf_mod.post_processing, jax.block_until_ready
+
+    def recording_pp(labels, emap, tau, **kw):
+        seen["labels"] = labels.copy()
+        seen["emap"] = {p: set(s) for p, s in emap.items()}
+        return real_pp(labels, emap, tau, **kw)
+
+    def recording_block(x):
+        f = sys._getframe(1)
+        while f is not None:
+            if f.f_code.co_name == "_rescue_and_finish":
+                blocks.append(f.f_code.co_name)
+                break
+            f = f.f_back
+        return real_block(x)
+
+    was = (obs.trace_enabled(), obs.metrics_enabled())
+    obs.enable(trace=trace, metrics_on=True)
+    obs.clear_trace()
+    metrics.reset()
+    laf_mod.post_processing, jax.block_until_ready = recording_pp, recording_block
+    try:
+        bk = RandomProjectionBackend(device=True, interpret=True, **CFG)
+        res = laf_dbscan(data, EPS, RESCUE_TAU, 1.0, pred, backend=bk, block_size=128)
+        return {"res": res, "bk": bk, "spans": obs.spans(), "blocks": blocks,
+                "counters": metrics.snapshot(), **seen}
+    finally:
+        laf_mod.post_processing, jax.block_until_ready = real_pp, real_block
+        obs.enable(trace=was[0], metrics_on=was[1])
+
+
+@pytest.fixture(scope="module")
+def rescue_case(obs_data):
+    return {"traced": _run_rescue_case(obs_data, True),
+            "untraced": _run_rescue_case(obs_data, False)}
+
+
+def _children(recs, parent):
+    return [r for r in recs if r.parent_id == parent.span_id]
+
+
+def test_rescue_spans_nest_under_postprocess_and_account_for_it(rescue_case):
+    case = rescue_case["traced"]
+    recs = case["spans"]
+    assert case["res"].extras["n_rescued"] > 0
+    (post,) = [r for r in recs if r.name == "laf.postprocess"]
+    kids = _children(recs, post)
+    names = [r.name for r in kids]
+    blocks = -(-int(case["res"].extras["n_predicted_core"]) // 128)
+    assert names.count("laf.rescue.sweep") == names.count("laf.rescue.emap") == blocks
+    assert names.count("laf.rescue.merge") == 1
+    assert set(names) == {"laf.rescue.sweep", "laf.rescue.emap", "laf.rescue.merge"}
+    assert sum(r.dur for r in kids) >= 0.9 * post.dur
+    for r in kids:
+        if r.name == "laf.rescue.sweep":
+            assert r.attrs["cols"] == case["res"].extras["n_rescued"]
+            assert r.dispatch_s is not None  # synced on its hits
+
+
+def test_rescue_counters_match_the_partial_neighbor_map(rescue_case):
+    case = rescue_case["traced"]
+    emap, labels, c = case["emap"], case["labels"], case["counters"]
+    assert c["laf.rescue.pairs"] == sum(len(s) for s in emap.values()) > 0
+    # one loop pass per (block, rescued point hit): at least one per entry
+    assert c["laf.rescue.visits"] >= len(emap) > 0
+    merged = sum(1 for s in emap.values() if len(s) >= RESCUE_TAU
+                 and (labels[np.fromiter(s, np.int64)] >= 0).any())
+    assert c["laf.rescue.merged"] == merged > 0
+    # the same work, counted the same, with tracing off
+    off = rescue_case["untraced"]["counters"]
+    for k in ("laf.rescue.pairs", "laf.rescue.visits", "laf.rescue.merged",
+              "index.upload.bytes"):
+        assert off[k] == c[k]
+
+
+def test_database_uploads_are_spanned_and_counted(rescue_case):
+    case = rescue_case["traced"]
+    recs, bk = case["spans"], case["bk"]
+    ups = [r for r in recs if r.name == "laf.upload"]
+    by_id = {r.span_id: r for r in recs}
+    n, d = bk._data.shape
+    words = bk._sigs.shape[1]
+    padded = -(-n // bk.db_tile) * bk.db_tile
+    want = {"corpus": (n, 4 * n * d), "data": (n, 4 * n * d),
+            "sigs": (n, 4 * n * words), "sweep_data": (padded, 4 * padded * d),
+            "sweep_sigs": (padded, 4 * padded * words)}
+    assert {r.attrs["what"]: (r.attrs["rows"], r.attrs["bytes"]) for r in ups} == want
+    assert len(ups) == len(want)
+    assert case["counters"]["index.upload.bytes"] == sum(b for _, b in want.values())
+    for r in ups:
+        parent = by_id[r.parent_id].name
+        assert parent == ("laf.fit_index" if r.attrs["what"] == "corpus" else "laf.sweep")
+        assert r.dispatch_s is not None  # synced on the device array
+
+
+def test_host_assembly_is_spanned_inside_the_cluster_span(rescue_case):
+    recs = rescue_case["traced"]["spans"]
+    (cluster,) = [r for r in recs if r.name == "laf.cluster"]
+    names = [r.name for r in _children(recs, cluster)]
+    assert names == ["laf.fit_index", "laf.pass1", "laf.label_prop", "laf.assemble",
+                     "laf.postprocess"]
+    assert obs.coverage(cluster, recs) >= 0.95
+
+
+def test_untraced_rescue_records_no_span_and_never_blocks(rescue_case):
+    case = rescue_case["untraced"]
+    assert case["res"].extras["n_rescued"] > 0
+    assert case["spans"] == []
+    assert case["blocks"] == []
+    # the traced run did sync from inside the rescue: the probe sees it
+    assert rescue_case["traced"]["blocks"]
+    np.testing.assert_array_equal(case["res"].labels, rescue_case["traced"]["res"].labels)
