@@ -28,7 +28,12 @@ import numpy as np
 from ..obs import device as _obs_device, get_logger, metrics as _metrics, rate_limited_warn, span as _span
 from ..testing import faults as _faults
 from .dbscan import NOISE, UNDEFINED, DBSCANResult
-from .postprocess import PartialNeighborMap, post_processing, update_partial_neighbors
+from .postprocess import (
+    PartialNeighborMap,
+    post_processing,
+    post_processing_incidence,
+    update_partial_neighbors,
+)
 from .range_query import pack_bitmap, unpack_bitmap
 from .union_find import compact_labels, compact_labels_from_parent, union_star
 
@@ -141,6 +146,9 @@ def laf_dbscan(
         ``True`` forces the device program even for host backends (the
         packed blocks are uploaded once — the exact-backend parity
         mode); ``False`` forces the host pass.
+      seed: accepted for symmetry with ``laf_dbscan_sequential``; the
+        rescue here draws no random destination (its partition does not
+        depend on one — see ``core/postprocess.py``), so it is unused.
       on_device_fault: ``"raise"`` (default) surfaces a failure of
         the device cluster launch; ``"degrade"`` (opt-in) falls back to
         the bit-exact host unpack → union-find pass instead (recording
@@ -155,7 +163,7 @@ def laf_dbscan(
     try:
         return _laf_dbscan_body(
             data, eps, tau, alpha, predicted_counts, as_fitted,
-            block_size=block_size, seed=seed, backend=backend, device=device,
+            block_size=block_size, backend=backend, device=device,
             cluster_device=cluster_device, on_device_fault=on_device_fault,
         )
     finally:
@@ -251,7 +259,7 @@ def _cluster_pass_device(bk, eps, tau, exec_idx, n, native, block_size):
 
 def _laf_dbscan_body(
     data, eps, tau, alpha, predicted_counts, as_fitted,
-    *, block_size, seed, backend, device, cluster_device="auto",
+    *, block_size, backend, device, cluster_device="auto",
     on_device_fault="raise",
 ):
     n = data.shape[0]
@@ -291,7 +299,7 @@ def _laf_dbscan_body(
         else:
             partial_counts[predicted_core] = 0  # 𝓔 keys: predicted-stop only
             return _rescue_and_finish(
-                bk, eps, tau, seed, block_size, n, exec_idx, predicted_core,
+                bk, eps, tau, block_size, n, exec_idx, predicted_core,
                 labels, core, partial_counts,
             )
 
@@ -342,46 +350,55 @@ def _laf_dbscan_body(
         borders = np.nonzero(~core & (owner >= 0))[0]
         labels[borders] = labels[owner[borders]]
     return _rescue_and_finish(
-        bk, eps, tau, seed, block_size, n, exec_idx, predicted_core,
+        bk, eps, tau, block_size, n, exec_idx, predicted_core,
         labels, core, partial_counts,
     )
 
 
 def _rescue_and_finish(
-    bk, eps, tau, seed, block_size, n, exec_idx, predicted_core,
+    bk, eps, tau, block_size, n, exec_idx, predicted_core,
     labels, core, partial_counts,
 ):
     """Post-processing rescue (Algorithm 3) + result assembly, shared by
-    the host and device cluster passes."""
+    the host and device cluster passes.  No Python loop runs per rescued
+    point or per hit: 𝓔 is built and merged as arrays."""
     n_exec = len(exec_idx)
     n_pre_clusters = int(labels.max()) + 1 if labels.max() >= 0 else 0
 
     # ---- post-processing: rescue false negatives (Algorithm 3) ---------
+    # 𝓔 is kept as arrays: |𝓔(P)| per rescued point and, per block, the
+    # distinct (pre-merge cluster, rescued point) keys of its members —
+    # all that Algorithm 3 reads (core/postprocess.py)
     rescue_idx = np.nonzero(~predicted_core & (partial_counts >= tau))[0]
-    _metrics.counter("laf.rescued").inc(int(len(rescue_idx)))
+    n_rescue = len(rescue_idx)
+    _metrics.counter("laf.rescued").inc(int(n_rescue))
+    emap_size = np.zeros(n_rescue, dtype=np.int64)
+    links = [np.zeros(0, dtype=np.int64)]
+    stride = max(n_rescue, 1)  # key = cluster * stride + rescued column
     pairs = visits = 0
-    with _span("laf.postprocess", n_rescue=int(len(rescue_idx))):
-        emap = PartialNeighborMap()
-        if len(rescue_idx) > 0:
+    with _span("laf.postprocess", n_rescue=int(n_rescue)):
+        if n_rescue > 0:
             for start in range(0, n_exec, block_size):
                 rows = exec_idx[start : start + block_size]
                 with _span("laf.rescue.sweep", rows=len(rows),
-                           cols=len(rescue_idx)) as sweep:
+                           cols=n_rescue) as sweep:
                     hit = bk.query_hits_subset(rows, rescue_idx, eps)  # (b, n_rescue)
                     sweep.sync_on(hit)
                 with _span("laf.rescue.emap", rows=len(rows)):
-                    hit_cols = np.nonzero(hit.any(axis=0))[0]
-                    for ri in hit_cols:
-                        r = int(rescue_idx[ri])
-                        emap.register(r)
-                        found = rows[hit[:, ri]]
-                        emap[r].update(int(f) for f in found)
-                        pairs += len(found)
-                visits += len(hit_cols)
-        with _span("laf.rescue.merge", entries=len(emap)):
-            labels = post_processing(labels, emap, tau, rng=np.random.default_rng(seed))
+                    i, j = np.divmod(np.flatnonzero(hit), n_rescue)
+                    per_col = np.bincount(j, minlength=n_rescue)
+                    emap_size += per_col
+                    pairs += len(j)
+                    visits += int(np.count_nonzero(per_col))
+                    cluster = labels[rows[i]]
+                    member = cluster >= 0
+                    links.append(np.unique(cluster[member] * stride + j[member]))
+        with _span("laf.rescue.merge", entries=int(np.count_nonzero(emap_size))):
+            keys = np.concatenate(links)
+            labels = post_processing_incidence(
+                labels, emap_size, keys // stride, keys % stride, rescue_idx, tau
+            )
             labels = _compact(labels)
-            del emap  # its sets of Python ints are freed here, inside the span
     _metrics.counter("laf.rescue.pairs").inc(pairs)
     _metrics.counter("laf.rescue.visits").inc(visits)
 

@@ -13,6 +13,22 @@ core point, and leaving it noise would contradict DBSCAN semantics; the
 paper's published code does the same (merge implies membership).
 Merging is transitive across rescue points; a union-find over cluster
 ids realizes exactly the sequential chain of merges.
+
+Two forms of Algorithm 3 live here:
+
+* ``post_processing`` reads 𝓔 as a ``PartialNeighborMap`` of Python
+  sets, verbatim.  It is the oracle: ``laf_dbscan_sequential`` and
+  LAF-DBSCAN++ (``dbscan_pp``) run it.
+* ``post_processing_incidence`` reads 𝓔 as arrays: |𝓔(P)| per rescued
+  point and the distinct (pre-merge cluster, rescued point) pairs among
+  its members.  Algorithm 3 needs nothing else, so ``laf_dbscan`` builds
+  only these, with array reductions over each block of subset hits.
+
+The two give the same partition.  The random destination cannot change
+it: every cluster of 𝓔(P) is unioned with the destination, so the
+destination's root after the merges is the root of any one of them,
+whichever member was drawn.  Only the label numbers before compaction
+may differ; the array form takes P's least cluster id as its anchor.
 """
 
 from __future__ import annotations
@@ -22,9 +38,14 @@ from typing import Dict, List, Optional, Set
 import numpy as np
 
 from ..obs import metrics as _metrics
-from .union_find import UnionFind
+from .union_find import UnionFind, find_roots_vec
 
-__all__ = ["PartialNeighborMap", "update_partial_neighbors", "post_processing"]
+__all__ = [
+    "PartialNeighborMap",
+    "update_partial_neighbors",
+    "post_processing",
+    "post_processing_incidence",
+]
 
 NOISE = -1
 UNDEFINED = -2
@@ -105,3 +126,59 @@ def post_processing(
     for p, dest in rescued:
         labels[p] = remap[dest]
     return labels
+
+
+def post_processing_incidence(
+    labels: np.ndarray,
+    emap_size: np.ndarray,
+    cluster_ids: np.ndarray,
+    point_cols: np.ndarray,
+    rescue_idx: np.ndarray,
+    tau: int,
+) -> np.ndarray:
+    """Algorithm 3 over the (pre-merge cluster, rescued point) incidence.
+
+    Point ``rescue_idx[j]`` has ``emap_size[j]`` = |𝓔| partial neighbors;
+    each pair ``(cluster_ids[k], point_cols[k])`` says that a member of
+    𝓔(``rescue_idx[point_cols[k]]``) lies in cluster ``cluster_ids[k]``
+    (pairs may repeat; noise has none).  Returns labels in the same id
+    space as ``post_processing``, with the same partition.  The distinct
+    pairs are counted into ``laf.rescue.links``, the points it assigns
+    into ``laf.rescue.merged``.
+    """
+    labels = labels.copy()
+    n_clusters = int(labels.max()) + 1 if labels.max() >= 0 else 0
+    if n_clusters == 0:
+        return labels
+    # sorted by point, then cluster: each point's first pair is its anchor
+    key = np.unique(
+        np.asarray(point_cols, dtype=np.int64) * n_clusters
+        + np.asarray(cluster_ids, dtype=np.int64)
+    )
+    _metrics.counter("laf.rescue.links").inc(len(key))
+    col, cluster = np.divmod(key, n_clusters)
+    keep = emap_size[col] >= tau
+    col, cluster = col[keep], cluster[keep]
+    first = np.ones(len(col), dtype=bool)
+    first[1:] = col[1:] != col[:-1]
+    anchor = cluster[first]
+    _metrics.counter("laf.rescue.merged").inc(len(anchor))
+    rest = ~first
+    root = _cluster_roots(n_clusters, anchor[np.cumsum(first)[rest] - 1], cluster[rest])
+    mask = labels >= 0
+    labels[mask] = root[labels[mask]]
+    labels[rescue_idx[col[first]]] = root[anchor]
+    return labels
+
+
+def _cluster_roots(n: int, a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    """Least id of each of ``n`` ids' component under the edges (a, b):
+    every round hooks the larger root of each edge that still crosses
+    two components onto the smaller."""
+    parent = np.arange(n, dtype=np.int64)
+    while len(a):
+        ra, rb = find_roots_vec(parent, a), find_roots_vec(parent, b)
+        cross = ra != rb
+        a, b, ra, rb = a[cross], b[cross], ra[cross], rb[cross]
+        np.minimum.at(parent, np.maximum(ra, rb), np.minimum(ra, rb))
+    return find_roots_vec(parent, np.arange(n))

@@ -4,7 +4,15 @@ import pytest
 from repro.core.dbscan import dbscan_sequential
 from repro.core.laf_dbscan import laf_dbscan, laf_dbscan_sequential
 from repro.core.metrics import adjusted_mutual_info, adjusted_rand_index
-from repro.core.postprocess import PartialNeighborMap, post_processing, update_partial_neighbors
+from repro import obs
+from repro.core.postprocess import (
+    PartialNeighborMap,
+    post_processing,
+    post_processing_incidence,
+    update_partial_neighbors,
+)
+from repro.core.union_find import compact_labels
+from repro.obs import metrics
 from repro.core.range_query import range_counts
 
 
@@ -125,6 +133,103 @@ class TestPartialNeighbors:
         emap[6].update({2, 3, 4})
         out = post_processing(labels, emap, 3)
         assert out[0] == out[2] == out[4]
+
+
+def _canonical(labels) -> list:
+    """Cluster ids renumbered in the order of their lowest-index point,
+    noise -1: two labelings hold one partition iff these are equal."""
+    labels = np.asarray(labels)
+    out = np.full(len(labels), -1)
+    pos = labels >= 0
+    _, first, inv = np.unique(labels[pos], return_index=True, return_inverse=True)
+    out[pos] = np.argsort(np.argsort(first))[inv]
+    return out.tolist()
+
+
+def _random_emap(seed: int):
+    """80 points over 6 clusters and noise; 12 noise points are rescue
+    candidates with 0 to 7 partial neighbors among the other points."""
+    rng = np.random.default_rng(seed)
+    labels = rng.integers(-1, 6, 80)
+    rescue = rng.choice(80, 12, replace=False)
+    labels[rescue] = -1
+    others = np.setdiff1d(np.arange(80), rescue)
+    return labels, {int(p): rng.choice(others, int(rng.integers(0, 8)), replace=False)
+                    for p in rescue}, 3
+
+
+def _chain_emap():
+    """12 clusters chained by 11 rescued points in a shuffled order, so
+    the merge takes several hooking rounds."""
+    order = np.random.default_rng(5).permutation(12)
+    labels = np.concatenate([np.repeat(np.arange(12), 2), np.full(11, -1)])
+    emap = {24 + k: np.array([2 * order[k], 2 * order[k] + 1, 2 * order[k + 1]])
+            for k in range(11)}
+    return labels, emap, 3
+
+
+INCIDENCE_CASES = {
+    # p 6 has 2 < tau partial neighbors and stays noise; p 7 merges 0, 2
+    "below_tau": (np.array([0, 0, 1, 1, 2, 2, -1, -1]),
+                  {6: np.array([0, 2]), 7: np.array([1, 4, 5])}, 3),
+    # p 5's partial neighbors are all noise: nothing to merge into
+    "members_all_noise": (np.array([0, 0, 1, 1, -1, -1, -1, -1]),
+                          {5: np.array([4, 6, 7])}, 3),
+    "transitive_chain": _chain_emap(),
+    "empty_rescue": (np.array([0, 0, 1, 1, -1]), {}, 3),
+    # p 4's members hit cluster 1 only: it joins it, nothing merges
+    "one_cluster": (np.array([0, 0, 1, 1, -1, 1]), {4: np.array([2, 3, 5])}, 3),
+    **{f"random_{seed}": _random_emap(seed) for seed in range(4)},
+}
+
+
+@pytest.fixture
+def metrics_on():
+    was_trace, was_metrics = obs.trace_enabled(), obs.metrics_enabled()
+    obs.enable(trace=False, metrics_on=True)
+    metrics.reset()
+    yield
+    metrics.reset()
+    if was_trace or was_metrics:
+        obs.enable(trace=was_trace, metrics_on=was_metrics)
+    else:
+        obs.disable()
+
+
+@pytest.mark.parametrize("case", sorted(INCIDENCE_CASES))
+def test_incidence_form_matches_the_oracle(case, metrics_on):
+    """Algorithm 3 over the (cluster, rescued point) incidence gives the
+    verbatim ``post_processing``'s partition and merged count."""
+    labels, members, tau = INCIDENCE_CASES[case]
+    emap = PartialNeighborMap()
+    for p, mem in members.items():
+        emap.register(p)
+        emap[p].update(int(q) for q in mem)
+    rescue_idx = np.array(sorted(members), dtype=np.int64)
+    emap_size = np.array([len(emap[p]) for p in rescue_idx], dtype=np.int64)
+    cols = np.concatenate([np.full(len(members[p]), j) for j, p in enumerate(rescue_idx)]
+                          + [np.zeros(0, np.int64)]).astype(np.int64)
+    points = np.concatenate([members[p] for p in rescue_idx] + [np.zeros(0, np.int64)])
+    cluster = labels[points.astype(np.int64)]
+    member = cluster >= 0
+
+    want = post_processing(labels, emap, tau)
+    want_merged = metrics.counter("laf.rescue.merged").value
+    metrics.reset()
+    got = post_processing_incidence(labels, emap_size, cluster[member], cols[member],
+                                    rescue_idx, tau)
+    assert _canonical(compact_labels(got)) == _canonical(want)
+    assert metrics.counter("laf.rescue.merged").value == want_merged
+    assert metrics.counter("laf.rescue.links").value == len(
+        set(zip(cluster[member].tolist(), cols[member].tolist())))
+    if case == "below_tau":
+        assert got[6] == -1 and got[7] == got[0] == got[4] != got[2]
+    if case == "transitive_chain":
+        assert len(set(got[got >= 0].tolist())) == 1
+    if case in ("members_all_noise", "empty_rescue"):
+        assert _canonical(got) == _canonical(labels)
+    if case == "one_cluster":
+        assert got[4] == got[2] != got[0]
 
 
 class TestFullyMissedClusters:

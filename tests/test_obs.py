@@ -286,8 +286,10 @@ RESCUE_TAU = 5
 def _run_rescue_case(data, trace: bool) -> dict:
     """One device-path LAF run (interpreted kernels) whose rescue is not
     empty: 40% of the points are predicted to stop whatever their count.
-    Records the spans, the counters, the map 𝓔 that Algorithm 3 received
-    and every ``jax.block_until_ready`` made from inside the rescue."""
+    Records the spans, the counters, the arrays that Algorithm 3 received
+    (|𝓔| per rescued point and the (cluster, point) incidence of its
+    members), its answer, and every ``jax.block_until_ready`` made from
+    inside the rescue."""
     import importlib
     import sys
 
@@ -301,12 +303,15 @@ def _run_rescue_case(data, trace: bool) -> dict:
     rng = np.random.default_rng(0)
     pred = np.where(rng.random(len(data)) < 0.4, 0.0, counts)
     seen, blocks = {}, []
-    real_pp, real_block = laf_mod.post_processing, jax.block_until_ready
+    real_pp, real_block = laf_mod.post_processing_incidence, jax.block_until_ready
 
-    def recording_pp(labels, emap, tau, **kw):
-        seen["labels"] = labels.copy()
-        seen["emap"] = {p: set(s) for p, s in emap.items()}
-        return real_pp(labels, emap, tau, **kw)
+    def recording_pp(labels, emap_size, cluster_ids, point_cols, rescue_idx, tau):
+        seen.update(labels=labels.copy(), emap_size=emap_size.copy(),
+                    cluster_ids=cluster_ids.copy(), point_cols=point_cols.copy(),
+                    rescue_idx=rescue_idx.copy())
+        seen["merged_labels"] = real_pp(labels, emap_size, cluster_ids, point_cols,
+                                        rescue_idx, tau)
+        return seen["merged_labels"]
 
     def recording_block(x):
         f = sys._getframe(1)
@@ -321,14 +326,14 @@ def _run_rescue_case(data, trace: bool) -> dict:
     obs.enable(trace=trace, metrics_on=True)
     obs.clear_trace()
     metrics.reset()
-    laf_mod.post_processing, jax.block_until_ready = recording_pp, recording_block
+    laf_mod.post_processing_incidence, jax.block_until_ready = recording_pp, recording_block
     try:
         bk = RandomProjectionBackend(device=True, interpret=True, **CFG)
         res = laf_dbscan(data, EPS, RESCUE_TAU, 1.0, pred, backend=bk, block_size=128)
         return {"res": res, "bk": bk, "spans": obs.spans(), "blocks": blocks,
-                "counters": metrics.snapshot(), **seen}
+                "counters": metrics.snapshot(), "pred": pred, **seen}
     finally:
-        laf_mod.post_processing, jax.block_until_ready = real_pp, real_block
+        laf_mod.post_processing_incidence, jax.block_until_ready = real_pp, real_block
         obs.enable(trace=was[0], metrics_on=was[1])
 
 
@@ -336,6 +341,17 @@ def _run_rescue_case(data, trace: bool) -> dict:
 def rescue_case(obs_data):
     return {"traced": _run_rescue_case(obs_data, True),
             "untraced": _run_rescue_case(obs_data, False)}
+
+
+def canonical(labels) -> list:
+    """Cluster ids renumbered in the order of their lowest-index point,
+    noise -1: two labelings hold one partition iff these are equal."""
+    labels = np.asarray(labels)
+    out = np.full(len(labels), -1)
+    pos = labels >= 0
+    _, first, inv = np.unique(labels[pos], return_index=True, return_inverse=True)
+    out[pos] = np.argsort(np.argsort(first))[inv]
+    return out.tolist()
 
 
 def _children(recs, parent):
@@ -361,18 +377,40 @@ def test_rescue_spans_nest_under_postprocess_and_account_for_it(rescue_case):
 
 
 def test_rescue_counters_match_the_partial_neighbor_map(rescue_case):
+    """The arrays the rescue hands Algorithm 3 are the oracle's map 𝓔,
+    rebuilt with Algorithm 2 from the same backend's hits, reduced; the
+    counters count that map; the merge gives the oracle's partition."""
+    from repro.core.postprocess import (PartialNeighborMap, post_processing,
+                                        update_partial_neighbors)
+
     case = rescue_case["traced"]
-    emap, labels, c = case["emap"], case["labels"], case["counters"]
-    assert c["laf.rescue.pairs"] == sum(len(s) for s in emap.values()) > 0
-    # one loop pass per (block, rescued point hit): at least one per entry
+    labels, c, rescue_idx = case["labels"], case["counters"], case["rescue_idx"]
+    exec_idx = np.nonzero(case["pred"] >= RESCUE_TAU)[0]
+    hit = case["bk"].query_hits_subset(exec_idx, rescue_idx, EPS)
+    emap = PartialNeighborMap()
+    for ri in np.nonzero(hit.any(axis=0))[0]:
+        emap.register(rescue_idx[ri])
+    for k, p in enumerate(exec_idx):
+        update_partial_neighbors(p, rescue_idx[hit[k]], emap)
+    np.testing.assert_array_equal(
+        case["emap_size"], [len(emap[r]) if r in emap else 0 for r in rescue_idx])
+    assert c["laf.rescue.pairs"] == sum(len(s) for _, s in emap.items()) > 0
+    # one count per (block, rescued point hit): at least one per entry
     assert c["laf.rescue.visits"] >= len(emap) > 0
-    merged = sum(1 for s in emap.values() if len(s) >= RESCUE_TAU
+    merged = sum(1 for _, s in emap.items() if len(s) >= RESCUE_TAU
                  and (labels[np.fromiter(s, np.int64)] >= 0).any())
     assert c["laf.rescue.merged"] == merged > 0
+    col = {int(r): j for j, r in enumerate(rescue_idx)}
+    links = {(int(labels[q]), col[p]) for p, s in emap.items() for q in s
+             if labels[q] >= 0}
+    assert set(zip(case["cluster_ids"].tolist(), case["point_cols"].tolist())) == links
+    assert 0 < c["laf.rescue.links"] == len(links) < c["laf.rescue.pairs"]
+    oracle = post_processing(labels, emap, RESCUE_TAU)
+    assert canonical(case["merged_labels"]) == canonical(oracle)
     # the same work, counted the same, with tracing off
     off = rescue_case["untraced"]["counters"]
     for k in ("laf.rescue.pairs", "laf.rescue.visits", "laf.rescue.merged",
-              "index.upload.bytes"):
+              "laf.rescue.links", "index.upload.bytes"):
         assert off[k] == c[k]
 
 
