@@ -24,6 +24,12 @@ from .rmi import RMIConfig, init_mlp, mlp_apply, rmi_predict, rmi_predict_counts
 
 __all__ = ["TrainedEstimator", "train_mlp", "train_rmi"]
 
+# rows per RMI dispatch.  On a v5e, 480,000 rows predicted in one batch
+# came back with 22,954 predictions near zero (the plain reference and
+# the same rows in blocks of this size agree on all but 9 skip
+# decisions); at 152,185 rows the blocks give bit-identical predictions
+PREDICT_BLOCK = 16384
+
 
 @dataclass
 class TrainedEstimator:
@@ -37,10 +43,19 @@ class TrainedEstimator:
         return rmi_predict(self.params, featurize(queries, eps), self.cfg)
 
     def predict_counts(self, queries, eps, *, reference_n: Optional[int] = None) -> np.ndarray:
-        """Predicted cardinalities.  ``reference_n`` rescales from the
-        training-split scale to a target dataset size (the paper instead
-        absorbs this gap in the per-dataset error factor α)."""
-        c = np.asarray(rmi_predict_counts(self.params, featurize(queries, eps), self.cfg))
+        """Predicted cardinalities, in blocks of ``PREDICT_BLOCK`` rows.
+        ``reference_n`` rescales from the training-split scale to a
+        target dataset size (the paper instead absorbs this gap in the
+        per-dataset error factor α)."""
+        per_row = np.size(eps) > 1
+        c = np.asarray(jnp.concatenate([
+            rmi_predict_counts(
+                self.params,
+                featurize(queries[s : s + PREDICT_BLOCK],
+                          eps[s : s + PREDICT_BLOCK] if per_row else eps),
+                self.cfg)
+            for s in range(0, max(len(queries), 1), PREDICT_BLOCK)
+        ]))
         if reference_n is not None and self.train_n:
             c = c * (reference_n / self.train_n)
         return c

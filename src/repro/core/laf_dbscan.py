@@ -230,6 +230,11 @@ def _cluster_pass_device(bk, eps, tau, exec_idx, n, native, block_size):
         _metrics.counter("laf.cluster.device_get").inc()
     rep, owner, col_sum, counts, rounds = outs_h[:5]
     _metrics.counter("laf.cluster.rounds").inc(int(rounds))
+    if mesh is not None:
+        from ..distributed.index_plane import count_cluster_collectives
+
+        count_cluster_collectives(len(rows_op), int(rounds), bk._plan.n_shards,
+                                  telemetry=telemetry)
     if telemetry and len(outs_h) > 5:
         per_round = _obs_device.harvest_cluster_telemetry(outs_h[5], rounds)
         _obs_device.emit_round_spans(getattr(lp_span, "_rec", None), per_round)

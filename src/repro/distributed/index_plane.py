@@ -20,7 +20,15 @@ cross the network —
   left sharded in place —
 
 never the (nq, n) boolean hit matrix, the database, or the signature
-table.  Plane-level padding (to a shard multiple of rows) uses zero
+table.  The ``plane.collective.bytes`` counter adds up what the
+collectives inside the plane's programs move per call: the count psums
+of every sweep and, after the cluster pass's one ``device_get``, its
+counts psum and one ``pmin`` of the (R,) s32 row minima per round.  The
+bitmap sweeps' word blocks leave their programs sharded (``P(None,
+axes)``), so no collective moves them; ``plane.gather.bytes`` counts
+what assembling them on the host would fetch.
+
+Plane-level padding (to a shard multiple of rows) uses zero
 rows with zero signatures, exactly the shape the kernel wrappers'
 ``_pad_col_hits`` correction was built for, so non-shard-multiple
 databases stay exact — including the eps > 1 corner where zero rows
@@ -40,9 +48,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..index.signatures import shard_signatures, unpack_bits
+from ..index.signatures import shard_signatures, unpack_bits, upload
 from ..obs import metrics as _metrics
 from ..kernels.hamming_filter.ops import (
     DEFAULT_DB_TILE,
@@ -64,6 +73,7 @@ __all__ = [
     "ShardPlan",
     "shard_plan",
     "shard_database",
+    "count_cluster_collectives",
     "sharded_hamming_count",
     "sharded_hamming_bitmap",
     "sharded_band_marginals",
@@ -76,22 +86,40 @@ I32 = jnp.int32
 
 
 def _count_collectives(kind: str, nq: int, n_chunks: int, n_shards: int,
-                       words: int = 0, pipelined: bool = False) -> None:
+                       words: int = 0, pipelined: bool = False,
+                       telemetry: bool = False) -> None:
     """Analytic per-call collective accounting (the traced program runs
     the psums, so they are counted here at dispatch, from the launch
     shape): each chunk's count psum moves ``chunk * 4`` bytes per shard
-    hop, bitmap gathers move each shard's word block to every peer."""
+    hop (and, with ``telemetry``, its (3,) s32 occupancy psum 12 more);
+    a bitmap launch's word blocks are what a host fetch would gather."""
     if not _metrics.enabled() or n_shards <= 1:
         return
     chunk = nq // max(n_chunks, 1)
+    psum_bytes = n_chunks * chunk * 4
     _metrics.counter("plane.psum.calls").inc(n_chunks)
-    _metrics.counter("plane.psum.bytes").inc(n_chunks * chunk * 4)
+    _metrics.counter("plane.psum.bytes").inc(psum_bytes)
+    _metrics.counter("plane.collective.bytes").inc(
+        psum_bytes + (n_chunks * 12 if telemetry else 0))
     if kind == "bitmap":
         _metrics.counter("plane.gather.calls").inc(1)
         _metrics.counter("plane.gather.bytes").inc(nq * words * 4)
     _metrics.counter(
         "plane.chunks.pipelined" if pipelined else "plane.chunks.serialized"
     ).inc(n_chunks)
+
+
+def count_cluster_collectives(n_rows: int, rounds: int, n_shards: int, *,
+                              telemetry: bool = False, max_iters: int = 64) -> None:
+    """Add one sharded cluster pass's collectives to
+    ``plane.collective.bytes``, from the slab's row count and the rounds
+    its single ``device_get`` brought back: the (R,) s32 counts psum,
+    one (R,) s32 ``pmin`` per round and, with ``telemetry``, the
+    (max_iters,) s32 psum of the shard gather wins."""
+    if not _metrics.enabled() or n_shards <= 1:
+        return
+    nbytes = 4 * n_rows * (1 + int(rounds)) + (4 * max_iters if telemetry else 0)
+    _metrics.counter("plane.collective.bytes").inc(nbytes)
 
 
 @dataclass(frozen=True)
@@ -149,10 +177,9 @@ def shard_database(mesh: Mesh, data, sigs, axes=None, *, tile: int = 32):
     mesh = auto_axes(mesh)
     plan = shard_plan(mesh, data.shape[0], axes, tile=tile)
     spec = P(plan.axes, None)
-    db = jax.device_put(
-        _pad_rows_to(jnp.asarray(data, jnp.float32), plan.n_padded),
-        NamedSharding(mesh, spec),
-    )
+    # each placed through one synced ``laf.upload`` span
+    db = upload(np.asarray(data, np.float32), "shard_data",
+                pad_rows=plan.n_pad, sharding=NamedSharding(mesh, spec))
     db_sig = shard_signatures(mesh, sigs, spec, n_padded=plan.n_padded)
     return db, db_sig, plan
 
@@ -506,7 +533,7 @@ def sharded_sweep_launch(
     )
     _count_collectives(
         kind, q.shape[0], q.shape[0] // chunk, axis_size(mesh, axes),
-        words=db.shape[0] // 32, pipelined=depth >= 2,
+        words=db.shape[0] // 32, pipelined=depth >= 2, telemetry=telemetry,
     )
     out = f(q, jnp.asarray(q_sig, jnp.uint32), db, db_sig, eps_op, band_op)
     return out, db.shape[0] - n

@@ -187,6 +187,9 @@ class RandomProjectionBackend(RangeBackend):
         self._sweep_dev = None
         self._host_sigs_dev = None
         self._plan = None
+        # under mesh=: the rescue's column side on the default device,
+        # ``(cols, data rows, signatures)``, kept until the next re-shard
+        self._subset_dev = None
         self.projection: Optional[np.ndarray] = None
         # eps values whose band occupancy was already measured into the
         # index.band.* metrics (one sampled pass per (backend, eps))
@@ -351,6 +354,8 @@ class RandomProjectionBackend(RangeBackend):
         if self.mesh is None:
             return
         from ..distributed.index_plane import shard_database
+
+        self._subset_dev = None
 
         # tile= aligns every shard to the kernel db tile so the sweep
         # engine's scanned kernel calls never re-pad inside the loop;
@@ -544,7 +549,8 @@ class RandomProjectionBackend(RangeBackend):
         indices are < n, so values are identical) instead of forcing a
         second, unpadded device copy into the cache."""
         if self.mesh is not None:
-            return jnp.asarray(self._data[rows]), jnp.asarray(self._sigs[rows])
+            return (upload(self._data[rows], "sweep_q"),
+                    upload(self._sigs[rows], "sweep_q_sigs"))
         db, dbs = self._sweep_db()
         ridx = jnp.asarray(rows)
         return db[ridx], dbs[ridx]
@@ -773,12 +779,12 @@ class RandomProjectionBackend(RangeBackend):
     def _dev_query_hits_subset(
         self, rows: np.ndarray, cols: np.ndarray, eps: float
     ) -> np.ndarray:
-        # gather the column side once, not per row chunk; subset
-        # queries stay single-device even under mesh= (the gathered
-        # column side is small, the row-sharded plane only pays off
-        # on whole-database sweeps)
+        # subset queries stay single-device even under mesh= (the
+        # column side is small, the row-sharded plane only pays off on
+        # whole-database sweeps); there the column side is gathered on
+        # the host and uploaded once for every block of a rescue
         if self.mesh is not None:
-            db, db_sig = jnp.asarray(self._data[cols]), jnp.asarray(self._sigs[cols])
+            db, db_sig = self._subset_cols(cols)
         elif self.sweep:
             sdb, sdbs = self._sweep_db()
             cidx = jnp.asarray(cols)
@@ -804,6 +810,19 @@ class RandomProjectionBackend(RangeBackend):
                 q, q_sig, db, db_sig, len(cols), eps
             )[: len(sub)]
         return hit
+
+    def _subset_cols(self, cols: np.ndarray):
+        """(rows, signatures) of ``cols`` on the default device, uploaded
+        when ``cols`` differs from the last call's: the rescue asks for
+        the same columns in every block of executed rows."""
+        cached = self._subset_dev
+        if cached is None or not np.array_equal(cached[0], cols):
+            cached = self._subset_dev = (
+                cols.copy(),
+                upload(self._data[cols], "rescue_cols"),
+                upload(self._sigs[cols], "rescue_col_sigs"),
+            )
+        return cached[1], cached[2]
 
     def _host_query_hits_subset(
         self, rows: np.ndarray, cols: np.ndarray, eps: float

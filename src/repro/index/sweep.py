@@ -443,8 +443,7 @@ def sweep_bitmap_device(
                         db_tile=db_tile, interpret=interpret, depth=depth, n=n,
                     )
                 parts.append(part)
-            bms = [p[1] for p in parts]
-            bm_out = jnp.concatenate(bms) if len(bms) > 1 else bms[0]
+            bm_out = _join_tail_masked([p[1] for p in parts], n)
         else:
             db, db_sig = _pad_db(db, db_sig, db_tile)
             donated = _resolve_donate(donate)
@@ -476,9 +475,18 @@ def sweep_bitmap_device(
                         interpret=interpret,
                     )
                 recompiles.delta()
-            bm_out = outs[1]
-        bm_out = bm_out & _tail_word_mask(bm_out.shape[1], n)[None, :]
+            bm_out = outs[1] & _tail_word_mask(outs[1].shape[1], n)[None, :]
         return bm_out, plan
+
+
+@functools.partial(jax.jit, static_argnames=("n",))
+def _join_tail_masked(blocks, n: int):
+    """The sharded launches' bitmap blocks as one slab, every bit for
+    columns >= n cleared, in one program: the slab is allocated once
+    beside its blocks.  A concatenation and then a mask held three
+    slabs at once (15.8 GB on the fullest of four v5e at 480,000 rows)."""
+    bm = jnp.concatenate(blocks)
+    return bm & _tail_word_mask(bm.shape[1], n)[None, :]
 
 
 def sweep_counts(

@@ -131,3 +131,31 @@ def test_calibrated_prediction(small_clustered):
     a = est.predict_counts(test[:8], 0.25)
     b = est.predict_counts(test[:8], 0.25, reference_n=len(test))
     np.testing.assert_allclose(b, a * len(test) / len(train), rtol=1e-5)
+
+
+@pytest.mark.parametrize("eps", [0.25, "per_row"])
+def test_predict_counts_runs_in_blocks(monkeypatch, eps):
+    """A batch longer than ``PREDICT_BLOCK`` is predicted block by block
+    and gives what one batch gives (on the CPU, where one batch is
+    sound), scalar or per-row eps alike."""
+    from repro.core.cardinality import training
+    from repro.core.cardinality.training import TrainedEstimator
+
+    cfg = RMIConfig(input_dim=9)
+    est = TrainedEstimator(init_rmi(jax.random.PRNGKey(1), cfg), cfg)
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(2), (50, 8)), np.float32)
+    e = np.linspace(0.1, 0.9, 50, dtype=np.float32) if eps == "per_row" else eps
+    whole = est.predict_counts(x, e)
+    batches = []
+    real = training.rmi_predict_counts
+
+    def counting(params, feats, c):
+        batches.append(feats.shape[0])
+        return real(params, feats, c)
+
+    monkeypatch.setattr(training, "PREDICT_BLOCK", 16)
+    monkeypatch.setattr(training, "rmi_predict_counts", counting)
+    blocked = est.predict_counts(x, e)
+    assert batches == [16, 16, 16, 2]
+    np.testing.assert_allclose(blocked, whole, rtol=1e-6, atol=1e-6)
+    assert est.predict_counts(x[:0], e if eps != "per_row" else e[:0]).shape == (0,)
