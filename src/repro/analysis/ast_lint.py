@@ -374,6 +374,7 @@ DISPATCH_CALLS = {
     "sharded_sweep_marginals", "sharded_band_marginals",
     "hamming_filter_pallas", "hamming_filter_count", "hamming_filter_bitmap",
     "query_hits", "query_counts", "query_hits_subset", "query_hits_packed",
+    "rescue_reduce",
     "partial_fit", "rmi_predict_counts", "predict_counts", "cluster_step",
 }
 _SYNC_CALLS = {"block_until_ready", "device_get", "sync_on"}

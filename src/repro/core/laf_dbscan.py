@@ -360,6 +360,36 @@ def _laf_dbscan_body(
     )
 
 
+def rescue_incidence(bk, carry, exec_idx, rescue_idx, labels, eps):
+    """``(emap_size, cluster_ids, point_cols, visits)`` from a rescue's
+    folded ``carry``, fetched in one ``device_get``: |𝓔| per rescued
+    point and its (pre-merge cluster, rescued column) pairs, the input
+    of Algorithm 3, and the carry's ``visits``.  A column holds exactly
+    the clusters ``cmin`` and ``cmax``, save the few whose members span
+    3 or more (``multi``): those are read again, exactly, with one subset
+    query of every executed row, and counted in ``laf.rescue.multi_cols``.
+    Pairs may repeat."""
+    import jax
+
+    count, cmin, cmax, multi, visits = (
+        np.asarray(v) for v in jax.device_get(tuple(carry))
+    )
+    col = np.arange(len(rescue_idx))
+    hit_one, hit_two = cmax >= 0, cmax > cmin
+    cluster_ids = [cmin[hit_one], cmax[hit_two]]
+    point_cols = [col[hit_one], col[hit_two]]
+    flagged = np.flatnonzero(multi)
+    _metrics.counter("laf.rescue.multi_cols").inc(len(flagged))
+    if len(flagged):
+        i, k = np.nonzero(bk.query_hits_subset(exec_idx, rescue_idx[flagged], eps))
+        cluster = labels[exec_idx[i]]
+        member = cluster >= 0
+        cluster_ids.append(cluster[member])
+        point_cols.append(flagged[k[member]])
+    return (count.astype(np.int64), np.concatenate(cluster_ids).astype(np.int64),
+            np.concatenate(point_cols).astype(np.int64), int(visits))
+
+
 def _rescue_and_finish(
     bk, eps, tau, block_size, n, exec_idx, predicted_core,
     labels, core, partial_counts,
@@ -371,40 +401,35 @@ def _rescue_and_finish(
     n_pre_clusters = int(labels.max()) + 1 if labels.max() >= 0 else 0
 
     # ---- post-processing: rescue false negatives (Algorithm 3) ---------
-    # 𝓔 is kept as arrays: |𝓔(P)| per rescued point and, per block, the
-    # distinct (pre-merge cluster, rescued point) keys of its members —
-    # all that Algorithm 3 reads (core/postprocess.py)
+    # 𝓔 is reduced per rescued point as the blocks sweep (``rescue_reduce``:
+    # on the device when the backend packs natively) and read once: |𝓔|
+    # and the distinct pre-merge clusters of its members are all that
+    # Algorithm 3 reads (core/postprocess.py)
     rescue_idx = np.nonzero(~predicted_core & (partial_counts >= tau))[0]
     n_rescue = len(rescue_idx)
     _metrics.counter("laf.rescued").inc(int(n_rescue))
     emap_size = np.zeros(n_rescue, dtype=np.int64)
-    links = [np.zeros(0, dtype=np.int64)]
-    stride = max(n_rescue, 1)  # key = cluster * stride + rescued column
-    pairs = visits = 0
+    cluster_ids = point_cols = np.zeros(0, dtype=np.int64)
+    visits = 0
     with _span("laf.postprocess", n_rescue=int(n_rescue)):
-        if n_rescue > 0:
+        if n_rescue and n_exec:
+            carry = None
             for start in range(0, n_exec, block_size):
                 rows = exec_idx[start : start + block_size]
                 with _span("laf.rescue.sweep", rows=len(rows),
                            cols=n_rescue) as sweep:
-                    hit = bk.query_hits_subset(rows, rescue_idx, eps)  # (b, n_rescue)
-                    sweep.sync_on(hit)
-                with _span("laf.rescue.emap", rows=len(rows)):
-                    i, j = np.divmod(np.flatnonzero(hit), n_rescue)
-                    per_col = np.bincount(j, minlength=n_rescue)
-                    emap_size += per_col
-                    pairs += len(j)
-                    visits += int(np.count_nonzero(per_col))
-                    cluster = labels[rows[i]]
-                    member = cluster >= 0
-                    links.append(np.unique(cluster[member] * stride + j[member]))
+                    carry = bk.rescue_reduce(rows, rescue_idx, labels, eps, carry)
+                    sweep.sync_on(carry)
+            with _span("laf.rescue.emap", cols=n_rescue):
+                emap_size, cluster_ids, point_cols, visits = rescue_incidence(
+                    bk, carry, exec_idx, rescue_idx, labels, eps
+                )
         with _span("laf.rescue.merge", entries=int(np.count_nonzero(emap_size))):
-            keys = np.concatenate(links)
             labels = post_processing_incidence(
-                labels, emap_size, keys // stride, keys % stride, rescue_idx, tau
+                labels, emap_size, cluster_ids, point_cols, rescue_idx, tau
             )
             labels = _compact(labels)
-    _metrics.counter("laf.rescue.pairs").inc(pairs)
+    _metrics.counter("laf.rescue.pairs").inc(int(emap_size.sum()))
     _metrics.counter("laf.rescue.visits").inc(visits)
 
     extras = {

@@ -19,11 +19,79 @@ instance; ``as_fitted`` normalizes all three.
 
 from __future__ import annotations
 
-from typing import Dict, List, Type, Union
+from typing import Any, Dict, List, NamedTuple, Type, Union
 
 import numpy as np
 
-__all__ = ["RangeBackend", "BACKENDS", "register_backend", "make_backend", "as_fitted"]
+__all__ = [
+    "RangeBackend", "RescueCarry", "fold_subset_hits", "BACKENDS",
+    "register_backend", "make_backend", "as_fitted",
+]
+
+NO_LABEL_MIN = np.iinfo(np.int32).max  # ``cmin`` of a column with no member yet
+
+
+class RescueCarry(NamedTuple):
+    """Running reduction of the rescue's subset hits, one entry per
+    rescued column.
+
+    ``count`` is |𝓔| so far, over every executed row, noise included;
+    ``cmin``/``cmax`` are the least and greatest pre-merge label among
+    the hit rows labelled >= 0 (``NO_LABEL_MIN`` and -1 when none);
+    ``multi`` is set once those labels hold 3 or more distinct values;
+    ``visits`` sums, over the folded blocks, the columns each block hit.
+    A column with ``multi`` clear has exactly the clusters ``cmin`` and
+    ``cmax``.  Every field is int32 (``multi`` bool), on the host or the
+    device alike.
+    """
+
+    count: Any
+    cmin: Any
+    cmax: Any
+    multi: Any
+    visits: Any
+
+    @classmethod
+    def empty(cls, width: int, xp=np) -> "RescueCarry":
+        """The carry of no rows: the identity of :meth:`merge`."""
+        return cls(
+            xp.zeros(width, xp.int32),
+            xp.full(width, NO_LABEL_MIN, xp.int32),
+            xp.full(width, -1, xp.int32),
+            xp.zeros(width, bool),
+            xp.zeros((), xp.int32),
+        )
+
+    def merge(self, other: "RescueCarry", xp=np) -> "RescueCarry":
+        """The carry of both carries' rows together.  Exact with constant
+        state: a column holds 3 or more labels iff one side does, or one
+        side's extreme lies strictly inside the joint range (a label stops
+        being an extreme only when a more extreme one arrives, so it is
+        caught at that step).  The sentinels never lie inside a range."""
+        lo = xp.minimum(self.cmin, other.cmin)
+        hi = xp.maximum(self.cmax, other.cmax)
+        multi = self.multi | other.multi
+        for v in (self.cmin, self.cmax, other.cmin, other.cmax):
+            multi = multi | ((v > lo) & (v < hi))
+        return RescueCarry(self.count + other.count, lo, hi, multi,
+                           self.visits + other.visits)
+
+
+def fold_subset_hits(carry, hit: np.ndarray, row_labels: np.ndarray) -> RescueCarry:
+    """``carry`` (``None``: no rows yet) merged with one block's boolean
+    ``(rows, cols)`` subset hits, whose rows carry ``row_labels``."""
+    lab = np.asarray(row_labels).astype(np.int32)[:, None]
+    member = hit & (lab >= 0)
+    lo = np.where(member, lab, NO_LABEL_MIN).min(axis=0, initial=NO_LABEL_MIN)
+    hi = np.where(member, lab, -1).max(axis=0, initial=-1)
+    count = hit.sum(axis=0, dtype=np.int32)
+    block = RescueCarry(
+        count, lo, hi, (member & (lab > lo) & (lab < hi)).any(axis=0),
+        np.int32(np.count_nonzero(count)),
+    )
+    if carry is None:
+        return block
+    return RescueCarry(*(np.asarray(v) for v in carry)).merge(block)
 
 
 class RangeBackend:
@@ -67,6 +135,25 @@ class RangeBackend:
     ) -> np.ndarray:
         """Boolean (len(rows), len(cols)) adjacency against db[cols]."""
         return self.query_hits(rows, eps)[:, cols]
+
+    def rescue_reduce(
+        self,
+        rows: np.ndarray,
+        cols: np.ndarray,
+        labels: np.ndarray,
+        eps: float,
+        carry: "RescueCarry | None" = None,
+    ) -> RescueCarry:
+        """Fold the hits of one block of executed ``rows`` on the rescued
+        ``cols`` into ``carry`` (``None`` starts a rescue); ``labels`` is
+        the pre-merge label of every database point (-1 noise).
+
+        This host fold reduces ``query_hits_subset``'s boolean hits; a
+        backend whose sweep packs natively reduces on the device and
+        fetches nothing until the caller reads the carry."""
+        return fold_subset_hits(
+            carry, self.query_hits_subset(rows, cols, eps), np.asarray(labels)[rows]
+        )
 
     @property
     def packs_natively(self) -> bool:

@@ -57,6 +57,7 @@ sets are identical (up to fp summation order on exact-boundary dots).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Union
 
@@ -76,7 +77,13 @@ from ..kernels.label_prop.kernel import WORD_TILE_ALIGN
 from ..obs import get_logger, metrics as _metrics, rate_limited_warn
 from ..testing import faults as _faults
 from ..train.fault_tolerance import GuardedStep
-from .base import RangeBackend, register_backend
+from .base import (
+    NO_LABEL_MIN,
+    RangeBackend,
+    RescueCarry,
+    fold_subset_hits,
+    register_backend,
+)
 from .signatures import (
     hamming_band,
     hamming_numpy,
@@ -87,6 +94,7 @@ from .signatures import (
 )
 from .sweep import (
     DEFAULT_CHUNKS_PER_LAUNCH,
+    _pad_db,
     sweep_bitmap,
     sweep_bitmap_device,
     sweep_counts,
@@ -96,6 +104,48 @@ __all__ = ["RandomProjectionBackend", "suggest_margin", "record_occupancy"]
 
 # jit'd full-database sweep (fused XOR+popcount+reduce)
 _hamming_sweep = jax.jit(hamming_words)
+
+# executed rows unpacked at a time by the rescue's fold: a block's
+# (rows, columns) hit matrix is never materialized whole
+_FOLD_ROWS = 256
+
+
+@functools.partial(jax.jit, static_argnames=("n_cols",))
+def _rescue_fold(carry, words, rows, n_rows, labels, *, n_cols: int):
+    """``carry`` merged with one block's packed subset hits ``words``
+    (``(nq_padded, W)`` uint32, LSB-first) of the executed ``rows``
+    (padded to ``nq_padded``): query rows ``>= n_rows`` are the sweep's
+    zero pad and columns ``>= n_cols`` its tile pad, both masked.
+    Unpacks ``_FOLD_ROWS`` rows at a time, bit-major as ``(32, rows, W)``
+    so no minor dimension is reshaped, and puts the block's reductions
+    in column order once."""
+    nq, n_words = words.shape
+    rc = math.gcd(nq, _FOLD_ROWS)
+    row_lab = labels[rows]
+    shifts = jnp.arange(32, dtype=jnp.uint32)[:, None, None]
+
+    def chunk(k, acc):
+        w = jax.lax.dynamic_slice_in_dim(words, k * rc, rc)
+        lab = jax.lax.dynamic_slice_in_dim(row_lab, k * rc, rc)[None, :, None]
+        live = (k * rc + jnp.arange(rc) < n_rows)[None, :, None]
+        hit = (((w[None] >> shifts) & 1) == 1) & live  # (32, rc, W)
+        member = hit & (lab >= 0)
+        lo = jnp.min(jnp.where(member, lab, NO_LABEL_MIN), axis=1)
+        hi = jnp.max(jnp.where(member, lab, -1), axis=1)
+        mid = jnp.any(member & (lab > lo[:, None]) & (lab < hi[:, None]), axis=1)
+        part = RescueCarry(jnp.sum(hit, axis=1, dtype=jnp.int32), lo, hi, mid,
+                           jnp.zeros((), jnp.int32))
+        return acc.merge(part, jnp)
+
+    block = jax.lax.fori_loop(
+        0, nq // rc, chunk, RescueCarry.empty((32, n_words), jnp)
+    )
+    # bit b of word w is column 32 w + b
+    block = RescueCarry(*(v.T.reshape(-1)[:n_cols] for v in block[:4]), block.visits)
+    block = block._replace(
+        visits=jnp.count_nonzero(block.count).astype(jnp.int32)
+    )
+    return carry.merge(block, jnp)
 
 
 @register_backend
@@ -187,9 +237,13 @@ class RandomProjectionBackend(RangeBackend):
         self._sweep_dev = None
         self._host_sigs_dev = None
         self._plan = None
-        # under mesh=: the rescue's column side on the default device,
+        # the rescue's column side on the default device, tile-padded,
         # ``(cols, data rows, signatures)``, kept until the next re-shard
         self._subset_dev = None
+        # ``(labels, device copy)`` of the running rescue's pre-merge
+        # labels, uploaded once per rescue (``rescue_reduce`` with
+        # ``carry=None``)
+        self._rescue_labels = None
         self.projection: Optional[np.ndarray] = None
         # eps values whose band occupancy was already measured into the
         # index.band.* metrics (one sampled pass per (backend, eps))
@@ -350,12 +404,12 @@ class RandomProjectionBackend(RangeBackend):
         """(Re-)place the database + signature table on the mesh; no-op
         without one.  Called at fit and after every append — the plane's
         row plan depends on n, so an append re-pads and re-places the
-        (host-resident) views in one ``device_put`` each."""
+        (host-resident) views in one ``device_put`` each.  Drops the
+        cached column side of subset queries, mesh or not."""
+        self._subset_dev = None
         if self.mesh is None:
             return
         from ..distributed.index_plane import shard_database
-
-        self._subset_dev = None
 
         # tile= aligns every shard to the kernel db tile so the sweep
         # engine's scanned kernel calls never re-pad inside the loop;
@@ -781,17 +835,8 @@ class RandomProjectionBackend(RangeBackend):
     ) -> np.ndarray:
         # subset queries stay single-device even under mesh= (the
         # column side is small, the row-sharded plane only pays off on
-        # whole-database sweeps); there the column side is gathered on
-        # the host and uploaded once for every block of a rescue
-        if self.mesh is not None:
-            db, db_sig = self._subset_cols(cols)
-        elif self.sweep:
-            sdb, sdbs = self._sweep_db()
-            cidx = jnp.asarray(cols)
-            db, db_sig = sdb[cidx], sdbs[cidx]
-        else:
-            cidx = jnp.asarray(cols)
-            db, db_sig = self._device_data()[cidx], self._device_sigs()[cidx]
+        # whole-database sweeps)
+        db, db_sig = self._subset_cols(cols)
         if self.sweep:
             from ..core.range_query import unpack_bitmap
 
@@ -806,23 +851,74 @@ class RandomProjectionBackend(RangeBackend):
         hit = np.zeros((len(rows), len(cols)), dtype=bool)
         for start, sub, padded in self._padded_chunks(rows):
             q, q_sig = self._q_block(padded)
+            # nd=len(cols) truncates the tile-pad columns off the bitmap
             hit[start : start + len(sub)] = self._device_hits(
                 q, q_sig, db, db_sig, len(cols), eps
             )[: len(sub)]
         return hit
 
     def _subset_cols(self, cols: np.ndarray):
-        """(rows, signatures) of ``cols`` on the default device, uploaded
-        when ``cols`` differs from the last call's: the rescue asks for
-        the same columns in every block of executed rows."""
+        """(rows, signatures) of ``cols`` on the default device, zero-padded
+        to the db tile, built when ``cols`` differs from the last call's:
+        the rescue asks for the same columns in every block of executed
+        rows.  One device gathers them from its own device copies; under
+        ``mesh=`` they are gathered on the host and uploaded."""
         cached = self._subset_dev
         if cached is None or not np.array_equal(cached[0], cols):
-            cached = self._subset_dev = (
-                cols.copy(),
-                upload(self._data[cols], "rescue_cols"),
-                upload(self._sigs[cols], "rescue_col_sigs"),
-            )
+            if self.mesh is not None:
+                db = upload(self._data[cols], "rescue_cols")
+                db_sig = upload(self._sigs[cols], "rescue_col_sigs")
+            else:
+                sdb, sdbs = (self._sweep_db() if self.sweep
+                             else (self._device_data(), self._device_sigs()))
+                cidx = jnp.asarray(cols)
+                db, db_sig = sdb[cidx], sdbs[cidx]
+            cached = self._subset_dev = (cols.copy(), *_pad_db(db, db_sig, self.db_tile))
         return cached[1], cached[2]
+
+    def rescue_reduce(
+        self,
+        rows: np.ndarray,
+        cols: np.ndarray,
+        labels: np.ndarray,
+        eps: float,
+        carry: Optional[RescueCarry] = None,
+    ) -> RescueCarry:
+        """Device fold when the sweep packs natively: the block's sweep
+        runs through the slab sweep's launches and its packed words feed
+        one jitted reduction, so nothing crosses to the host until the
+        caller reads the carry.  ``carry=None`` uploads ``labels`` for
+        the rescue it starts."""
+        if not self.packs_natively:
+            return super().rescue_reduce(rows, cols, labels, eps, carry)
+        rows = np.asarray(rows, dtype=np.int64)
+        cols = np.asarray(cols, dtype=np.int64)
+        return self._guard_device(
+            "subset",
+            lambda: self._dev_rescue_reduce(rows, cols, labels, eps, carry),
+            lambda: fold_subset_hits(
+                carry, self._host_query_hits_subset(rows, cols, eps),
+                np.asarray(labels)[rows],
+            ),
+        )
+
+    def _dev_rescue_reduce(self, rows, cols, labels, eps, carry):
+        held = self._rescue_labels
+        if carry is None or held is None or held[0] is not labels:
+            self._rescue_labels = (labels, jnp.asarray(np.asarray(labels, np.int32)))
+        db, db_sig = self._subset_cols(cols)
+        _faults.maybe_fail(self._launch_site, op="subset")
+        t_lo, t_hi = self.band(eps)
+        q, q_sig = self._sweep_q(rows)
+        words, plan = sweep_bitmap_device(
+            q, q_sig, db, db_sig, len(cols), eps, t_lo, t_hi, **self._sweep_kw()
+        )
+        padded = np.zeros(plan.nq_padded, dtype=np.int32)
+        padded[: len(rows)] = rows
+        if carry is None:
+            carry = RescueCarry.empty(len(cols), jnp)
+        return _rescue_fold(carry, words, padded, jnp.int32(len(rows)),
+                            self._rescue_labels[1], n_cols=len(cols))
 
     def _host_query_hits_subset(
         self, rows: np.ndarray, cols: np.ndarray, eps: float

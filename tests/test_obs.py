@@ -366,8 +366,8 @@ def test_rescue_spans_nest_under_postprocess_and_account_for_it(rescue_case):
     kids = _children(recs, post)
     names = [r.name for r in kids]
     blocks = -(-int(case["res"].extras["n_predicted_core"]) // 128)
-    assert names.count("laf.rescue.sweep") == names.count("laf.rescue.emap") == blocks
-    assert names.count("laf.rescue.merge") == 1
+    assert names.count("laf.rescue.sweep") == blocks
+    assert names.count("laf.rescue.emap") == names.count("laf.rescue.merge") == 1
     assert set(names) == {"laf.rescue.sweep", "laf.rescue.emap", "laf.rescue.merge"}
     assert sum(r.dur for r in kids) >= 0.9 * post.dur
     for r in kids:
@@ -412,6 +412,22 @@ def test_rescue_counters_match_the_partial_neighbor_map(rescue_case):
     for k in ("laf.rescue.pairs", "laf.rescue.visits", "laf.rescue.merged",
               "laf.rescue.links", "index.upload.bytes"):
         assert off[k] == c[k]
+
+
+def test_rescue_rereads_exactly_the_points_in_three_clusters(rescue_case):
+    """``laf.rescue.multi_cols`` counts the rescued points whose partial
+    neighbours lie in 3 or more pre-merge clusters: the columns the
+    device carry cannot name by its least and greatest label alone."""
+    case = rescue_case["traced"]
+    labels, rescue_idx = case["labels"], case["rescue_idx"]
+    exec_idx = np.nonzero(case["pred"] >= RESCUE_TAU)[0]
+    hit = case["bk"].query_hits_subset(exec_idx, rescue_idx, EPS)
+    rows_lab = labels[exec_idx]
+    spans = [len(set(rows_lab[hit[:, j] & (rows_lab >= 0)].tolist()))
+             for j in range(len(rescue_idx))]
+    want = sum(k >= 3 for k in spans)
+    for run in ("traced", "untraced"):
+        assert rescue_case[run]["counters"].get("laf.rescue.multi_cols", 0) == want
 
 
 def test_database_uploads_are_spanned_and_counted(rescue_case):

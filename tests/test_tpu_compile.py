@@ -143,6 +143,28 @@ def test_packed_cluster_program_compiles(one_chip):
     assert _bytes(compiled) < V5E_HBM
 
 
+def test_rescue_fold_program_compiles_in_bounded_memory(one_chip):
+    """The rescue's per-block reduction at MS-150k's rescue width (22,273
+    columns, tile-padded): it unpacks 256 rows at a time, so its scratch
+    stays far below one (2,048 rows x columns) int32 matrix."""
+    from repro.index.base import RescueCarry
+    from repro.index.random_projection import _rescue_fold
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n_cols = 22_273
+    width = -(-n_cols // 256) * 256
+    rows = CHUNK * CPL
+    carry = RescueCarry(s((n_cols,), jnp.int32), s((n_cols,), jnp.int32),
+                        s((n_cols,), jnp.int32), s((n_cols,), jnp.bool_), s((), jnp.int32))
+    compiled = _rescue_fold.trace(
+        carry, s((rows, width // 32), jnp.uint32), s((rows,), jnp.int32),
+        s((), jnp.int32), s((N,), jnp.int32), n_cols=n_cols,
+    ).lower().compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * width * 4 // 4
+
+
 def test_sharded_sweep_program_compiles_4chip(mesh4):
     from repro.distributed.index_plane import _build_sweep_plane_fn, shard_plan
 
